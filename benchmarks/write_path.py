@@ -51,6 +51,7 @@ from repro.core import manifest as mf
 from repro.core import packing
 from repro.core.snapshot import Snapshot
 from repro.core.storage import ObjectStore
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_workload(tables: int, rows: int, dim: int, seed: int = 0,
@@ -1136,6 +1137,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true", help="CI smoke sizes")
     ap.add_argument("--out", default="BENCH_write_path.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.tiny:
         args.tables, args.rows, args.dim = 2, 8192, 32
         args.chunk_rows, args.pack_codes = 1024, 262_144

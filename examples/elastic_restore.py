@@ -16,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_cell
 from repro.core import CheckpointConfig, InMemoryStore
 from repro.data.cells import batch_for_cell
+from repro.launch.mesh import make_host_mesh
 from repro.train.loop import Trainer, TrainerConfig
 from repro.train.state import restore_train_state
 
@@ -26,7 +27,7 @@ def main():
                             quant=None, async_write=False)
 
     # phase 1: train on a 4×2 mesh
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh8 = make_host_mesh(4, 2)
     bundle8 = get_cell("dlrm-rm2", "train_batch", mesh=mesh8, reduced=True)
     t1 = Trainer(bundle8, store, ckpt, TrainerConfig(total_steps=8))
     t1.init_or_restore()
@@ -41,8 +42,7 @@ def main():
     t1.close()
 
     # phase 2: restore the same checkpoint on a 2×2 mesh (4 devices)
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"),
-                          devices=jax.devices()[:4])
+    mesh4 = make_host_mesh(2, 2)
     bundle4 = get_cell("dlrm-rm2", "train_batch", mesh=mesh4, reduced=True)
     t2 = Trainer(bundle4, store, ckpt, TrainerConfig(total_steps=12))
     start = t2.init_or_restore()

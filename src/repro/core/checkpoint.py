@@ -370,48 +370,13 @@ class CheckNRunManager:
         return self.config.quant
 
     # ------------------------------------------------------ chunk quantization
-    _quant_ops = None  # class-level cache for the lazy kernel import
-
-    @classmethod
-    def _kernel_quant_ops(cls):
-        """Lazy import: pulls in the kernels package (and its model deps)
-        only when a quantized config is actually used. Returns
-        (quant_pack, quant_codes) or None."""
-        if cls._quant_ops is None:
-            try:
-                from ..kernels.adaptive_quant import quant_codes, quant_pack
-                cls._quant_ops = (quant_pack, quant_codes)
-            except ImportError:
-                # missing optional dep in this environment → jnp fallback;
-                # real kernel bugs (anything else) must surface, not be
-                # silently masked by the per-table numpy path
-                cls._quant_ops = False
-        return cls._quant_ops or None
-
-    _hash_ops = None  # class-level cache for the lazy chunk-hash import
-
-    @classmethod
-    def _kernel_hash_ops(cls):
-        """Lazy import of the on-device content hash (mirrors
-        :meth:`_kernel_quant_ops`). Returns (chunk_hash32_device,
-        chunk_hash32, impl_map) or None."""
-        if cls._hash_ops is None:
-            try:
-                from ..kernels.chunk_hash.ops import (_impl_for,
-                                                      chunk_hash32,
-                                                      chunk_hash32_device)
-                cls._hash_ops = (chunk_hash32_device, chunk_hash32, _impl_for)
-            except ImportError:
-                cls._hash_ops = False
-        return cls._hash_ops or None
-
     def _payload_hash32(self, payload: bytes) -> Optional[int]:
         """Host-side content hash of a serialized section (the fallback
         when the packed words never lived on device)."""
-        ops = self._kernel_hash_ops()
-        if not self.config.chunk_hash or ops is None:
+        if not self.config.chunk_hash:
             return None
-        return ops[1](payload)
+        from ..kernels.chunk_hash.ref import chunk_hash32
+        return chunk_hash32(payload)
 
     def _quant_encode(self, rows_arr: np.ndarray, qcfg: QuantConfig):
         """Quantize + bit-pack one chunk of rows. Returns (scale f32,
@@ -430,30 +395,30 @@ class CheckNRunManager:
         them, a coverage the host-computed crc32 cannot give. The host
         fallbacks hash the serialized payload; byte-identical payloads
         mean identical hashes either way."""
-        ops = self._kernel_quant_ops()
-        hash_ops = (self._kernel_hash_ops()
-                    if self.config.chunk_hash else None)
-        if ops is not None and qcfg.method in ("adaptive", "uniform_asym"):
-            quant_pack_op, quant_codes_op = ops
-            import jax.numpy as jnp
-            xj = jnp.asarray(rows_arr, dtype=jnp.float32)
+        if qcfg.method in ("adaptive", "uniform_asym"):
+            # lazy: JAX and the kernels load only once a quantized save runs
+            from ..kernels.adaptive_quant import quant_codes, quant_pack
+            from ..kernels.chunk_hash.ops import _impl_for, chunk_hash32_device
+            # host rows go in as numpy: quant_pack pads them to a row
+            # bucket on the host before the upload
+            xj = np.asarray(rows_arr, dtype=np.float32)
             kw = dict(bits=qcfg.bits, method=qcfg.method,
                       num_bins=qcfg.num_bins, ratio=qcfg.ratio,
                       impl=self.config.quant_impl)
             if self.config.fused_pack:
-                pq = quant_pack_op(xj, **kw)
+                pq = quant_pack(xj, **kw)
                 h = None
-                if hash_ops is not None:
-                    hash_dev, _, impl_for = hash_ops
+                if self.config.chunk_hash:
                     # hash exactly the words the payload serializes:
                     # ceil(payload_nbytes / 4), tail bits zero by packing
                     nbytes = (int(pq.count) * qcfg.bits + 7) // 8
-                    h = hash_dev(pq.words, count=(nbytes + 3) // 4,
-                                 impl=impl_for(self.config.quant_impl))
-                return (np.asarray(pq.scale), np.asarray(pq.zero),
+                    h = chunk_hash32_device(
+                        pq.words, count=(nbytes + 3) // 4,
+                        impl=_impl_for(self.config.quant_impl))
+                return (pq.scale, pq.zero,
                         packing.words_to_payload(np.asarray(pq.words),
                                                  pq.count, qcfg.bits), h)
-            q = quant_codes_op(xj, **kw)
+            q = quant_codes(xj, **kw)
             payload = packing.pack_bits(np.asarray(q.codes), qcfg.bits)
             return (np.asarray(q.scale), np.asarray(q.zero), payload,
                     self._payload_hash32(payload))

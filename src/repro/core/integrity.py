@@ -138,7 +138,7 @@ class ScanReport:
 
 def _hash32(payload: bytes) -> int:
     # lazy: pulls in the kernels package only when a hash is actually
-    # recorded (mirrors checkpoint._kernel_quant_ops)
+    # recorded
     from ..kernels.chunk_hash.ref import chunk_hash32
     return chunk_hash32(payload)
 

@@ -191,12 +191,16 @@ def rewrite_spill_layout(spill_dir: str, num_hosts: int) -> None:
 # ------------------------------------------------------------ process launch
 def child_env() -> Dict[str, str]:
     """Environment for a host process: ensures the running ``repro`` tree
-    is importable regardless of the launcher's own sys.path setup."""
+    is importable regardless of the launcher's own sys.path setup, and
+    keeps the child off the accelerator. A chip belongs to one process and
+    the launching trainer already holds it, so host processes encode on
+    the host CPU (``JAX_PLATFORMS=cpu``)."""
     src = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     env = dict(os.environ)
     prior = env.get("PYTHONPATH", "")
     env["PYTHONPATH"] = src + (os.pathsep + prior if prior else "")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

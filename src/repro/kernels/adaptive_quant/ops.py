@@ -17,10 +17,10 @@ Two generations of API:
   the write-path bench assert.
 
 Both support ``method`` "adaptive" (greedy search, §4.2.3) and
-"uniform_asym" (§4.2.1, the search degenerated to zero steps). The search
-uses the r-space error form (see ``kernel.py``) — ~1.7x fewer host ops per
-candidate than the textbook dequantize round-trip, same greedy decisions up
-to f32 rounding ties.
+"uniform_asym" (§4.2.1, the search degenerated to zero steps). Both device
+paths trace the same ``kernel.quantize_rows``, written to compile to the
+same bits under Mosaic and XLA, so on one chip the Pallas and jnp payloads
+are byte-identical.
 """
 
 from __future__ import annotations
@@ -30,21 +30,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.quantize import Quantized
 from .kernel import (
     adaptive_quant_pallas,
     pack_codes_u32,
     quant_pack_pallas,
+    quantize_rows,
 )
 from .ref import adaptive_quant_ref
-
-
-def _backend_is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
 
 
 def _round_up(n: int, m: int) -> int:
@@ -54,8 +49,9 @@ def _round_up(n: int, m: int) -> int:
 def _bucket_rows(rows: int) -> int:
     """Pad row counts to the next power of two (min 256) so ragged
     incremental selections hit a handful of jit cache entries instead of
-    compiling per chunk size. Quantization is row-wise, so zero padding
-    rows are inert and sliced off."""
+    compiling per chunk size — on both the jnp and the Pallas path.
+    Quantization is row-wise, so zero padding rows are inert and sliced
+    off."""
     n = 256
     while n < rows:
         n <<= 1
@@ -78,7 +74,7 @@ def adaptive_quant(x: jax.Array, bits: int = 4, num_bins: int = 45,
     """
     rows, dim = x.shape
     if impl == "auto":
-        impl = "pallas" if _backend_is_tpu() else "ref"
+        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl == "ref" or rows == 0:
         codes, scale, zero = adaptive_quant_ref(x, bits=bits, num_bins=num_bins,
                                                 ratio=ratio)
@@ -104,19 +100,20 @@ def adaptive_quant(x: jax.Array, bits: int = 4, num_bins: int = 45,
 
 @dataclasses.dataclass
 class PackedQuant:
-    """Device-packed quantization result.
+    """Quantize+pack result.
 
-    ``words``  uint32 (ceil(count*bits/32),) — the little-endian bit stream
-               (``core.packing.words_to_payload`` turns it into the exact
-               ``pack_bits`` byte payload)
-    ``scale``  f32 (rows,)
-    ``zero``   f32 (rows,)
+    ``words``  uint32 device array — the little-endian bit stream of the
+               row-padded chunk; its first ``ceil(count*bits/32)`` words
+               are the payload (``core.packing.words_to_payload`` cuts the
+               exact ``pack_bits`` bytes), the rest are zero padding
+    ``scale``  f32 (rows,) host array
+    ``zero``   f32 (rows,) host array
     ``count``  number of valid codes (= rows * dim)
     """
 
     words: jax.Array
-    scale: jax.Array
-    zero: jax.Array
+    scale: np.ndarray
+    zero: np.ndarray
     bits: int
     count: int
 
@@ -134,64 +131,32 @@ def _resolve_steps(method: str, bits: int, num_bins, ratio):
     return num_bins, int(ratio * num_bins)
 
 
-def _err_pair(x, lo1, hi1, lo2, hi2, levels):
-    """Both greedy candidates' errors from ONE traversal of ``x``: the two
-    per-row sums reduce through a single variadic ``lax.reduce``, so the
-    elementwise producers fuse into one loop over x instead of two. The
-    search is memory-bound (x is read ~2·n_steps times), so this halves
-    the hot loop's traffic. Same per-candidate math as
-    :func:`kernel._search_range` — bit-identical decisions."""
-    s1 = jnp.where(hi1 - lo1 > 0, (hi1 - lo1) / levels, 1.0)
-    s2 = jnp.where(hi2 - lo2 > 0, (hi2 - lo2) / levels, 1.0)
-    r1 = (x - lo1) * (1.0 / s1)
-    r2 = (x - lo2) * (1.0 / s2)
-    d1 = r1 - jnp.round(jnp.clip(r1, 0.0, levels))
-    d2 = r2 - jnp.round(jnp.clip(r2, 0.0, levels))
-    e1, e2 = jax.lax.reduce(
-        (jnp.square(d1), jnp.square(d2)),
-        (jnp.float32(0), jnp.float32(0)),
-        lambda a, b: (a[0] + b[0], a[1] + b[1]), (1,))
-    return (jnp.square(s1[:, 0]) * e1)[:, None], \
-        (jnp.square(s2[:, 0]) * e2)[:, None]
+def _resolve_impl(impl: str) -> str:
+    """"auto" → the fused Pallas kernel on a TPU backend, jnp elsewhere."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    return impl
+
+
+def _pad_rows(x, rows_pad: int):
+    """Zero-pad rows to ``rows_pad``: on the host for numpy input, so the
+    device sees only bucketed shapes and nothing compiles per chunk."""
+    rows = x.shape[0]
+    if rows == rows_pad:
+        return x
+    if isinstance(x, np.ndarray):
+        return np.pad(x.astype(np.float32, copy=False),
+                      ((0, rows_pad - rows), (0, 0)))
+    return jnp.pad(x.astype(jnp.float32), ((0, rows_pad - rows), (0, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "num_bins", "n_steps"))
 def _quant_jnp(x, bits: int, num_bins: int, n_steps: int):
-    """The jnp quantizer both fused and fallback paths share: r-space greedy
-    search (or none) with paired-candidate error evaluation, then the exact
-    reference affine code emission."""
-    x = x.astype(jnp.float32)
-    levels = float((1 << bits) - 1)
-    x_min0 = jnp.min(x, axis=-1, keepdims=True)
-    x_max0 = jnp.max(x, axis=-1, keepdims=True)
-    if n_steps == 0:
-        best_min, best_max = x_min0, x_max0
-    else:
-        step = (x_max0 - x_min0) / num_bins
-        err0, _ = _err_pair(x, x_min0, x_max0, x_min0, x_max0, levels)
-
-        def body(_, carry):
-            cur_min, cur_max, best_min, best_max, best_err = carry
-            err_lo, err_hi = _err_pair(x, cur_min + step, cur_max,
-                                       cur_min, cur_max - step, levels)
-            take_lo = err_lo <= err_hi
-            new_min = jnp.where(take_lo, cur_min + step, cur_min)
-            new_max = jnp.where(take_lo, cur_max, cur_max - step)
-            cur_err = jnp.where(take_lo, err_lo, err_hi)
-            improve = cur_err < best_err
-            best_min = jnp.where(improve, new_min, best_min)
-            best_max = jnp.where(improve, new_max, best_max)
-            best_err = jnp.where(improve, cur_err, best_err)
-            return new_min, new_max, best_min, best_max, best_err
-
-        init = (x_min0, x_max0, x_min0, x_max0, err0)
-        _, _, best_min, best_max, _ = jax.lax.fori_loop(0, n_steps, body,
-                                                        init)
-    rng = best_max - best_min
-    scale = jnp.where(rng > 0, rng / levels, 1.0)
-    q = jnp.round((jnp.clip(x, best_min, best_max) - best_min) / scale)
-    codes = jnp.clip(q, 0.0, levels).astype(jnp.uint8)
-    return codes, scale[:, 0], best_min[:, 0]
+    """The jnp device quantizer: the kernel's own :func:`quantize_rows`,
+    compiled by XLA."""
+    codes, scale, zero = quantize_rows(x.astype(jnp.float32), bits=bits,
+                                       num_bins=num_bins, n_steps=n_steps)
+    return codes.astype(jnp.uint8), scale[:, 0], zero[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bits",))
@@ -203,7 +168,7 @@ def _pack_jnp(codes, bits: int):
     return pack_codes_u32(codes.reshape(-1).astype(jnp.uint32), bits)
 
 
-def quant_codes(x: jax.Array, *, bits: int, method: str = "adaptive",
+def quant_codes(x, *, bits: int, method: str = "adaptive",
                 num_bins=None, ratio=None, block_rows: int = 256,
                 impl: str = "auto") -> Quantized:
     """The fused-path quantizer WITHOUT the device pack — for the host
@@ -211,71 +176,54 @@ def quant_codes(x: jax.Array, *, bits: int, method: str = "adaptive",
     bit-identical to :func:`quant_pack`'s (same compiled search)."""
     rows, dim = x.shape
     num_bins, n_steps = _resolve_steps(method, bits, num_bins, ratio)
-    if impl == "auto":
-        impl = "pallas" if _backend_is_tpu() else "jnp"
-    if impl in ("jnp", "ref") or rows == 0:
-        if rows == 0:
-            z = jnp.zeros((0,), jnp.float32)
-            return Quantized(jnp.zeros((0, dim), jnp.uint8), z, z, bits=bits)
-        rows_pad = _bucket_rows(rows)
-        xp = x.astype(jnp.float32)
-        if rows_pad != rows:
-            xp = jnp.pad(xp, ((0, rows_pad - rows), (0, 0)))
+    impl = _resolve_impl(impl)
+    if rows == 0:
+        z = jnp.zeros((0,), jnp.float32)
+        return Quantized(jnp.zeros((0, dim), jnp.uint8), z, z, bits=bits)
+    if impl in ("jnp", "ref"):
+        xp = _pad_rows(x, _bucket_rows(rows))
         codes, scale, zero = _quant_jnp(xp, bits, num_bins, n_steps)
         return Quantized(codes[:rows], scale[:rows], zero[:rows], bits=bits)
-    # pallas/interpret: reuse the fused kernel minus packing via the
-    # unpacked kernel? The fused kernel is the validated artifact, so run
-    # it and unpack on device to stay bit-identical with quant_pack.
+    # pallas/interpret: the fused kernel is the validated artifact, so run
+    # it and unpack on the host to stay bit-identical with quant_pack
     pq = quant_pack(x, bits=bits, method=method, num_bins=num_bins,
                     ratio=ratio, block_rows=block_rows, impl=impl)
     from ...core import packing as _packing
-    import numpy as np
     codes = _packing.unpack_bits(
         _packing.words_to_payload(np.asarray(pq.words), pq.count, bits),
         bits, pq.count).reshape(rows, dim)
     return Quantized(jnp.asarray(codes), pq.scale, pq.zero, bits=bits)
 
 
-def quant_pack(x: jax.Array, *, bits: int, method: str = "adaptive",
+def quant_pack(x, *, bits: int, method: str = "adaptive",
                num_bins=None, ratio=None, block_rows: int = 256,
                impl: str = "auto") -> PackedQuant:
-    """Fused quantize + bit-pack: (rows, dim) f32 → packed uint32 words +
-    per-row scale/zero, entirely on device.
+    """Fused quantize + bit-pack: (rows, dim) f32 (numpy or device) →
+    packed uint32 words on device + per-row scale/zero.
 
     impl: "auto" (fused Pallas kernel on TPU, jitted jnp elsewhere),
-    "pallas", "interpret", "jnp".
+    "pallas", "interpret", "jnp". Rows pad to power-of-two buckets on both
+    paths, so ragged incremental chunks share a few compiled programs.
     """
     rows, dim = x.shape
     num_bins, n_steps = _resolve_steps(method, bits, num_bins, ratio)
     count = rows * dim
-    if impl == "auto":
-        impl = "pallas" if _backend_is_tpu() else "jnp"
+    impl = _resolve_impl(impl)
 
     if count == 0:
-        z = jnp.zeros((0,), jnp.float32)
+        z = np.zeros((0,), np.float32)
         return PackedQuant(jnp.zeros((0,), jnp.uint32), z, z, bits, 0)
+    # _bucket_rows pads to a power of two ≥ 256, so the padded code stream
+    # splits into whole 32-code groups and whole kernel blocks
+    rows_pad = _bucket_rows(rows)
+    xp = _pad_rows(x, rows_pad)
     if impl in ("jnp", "ref"):
-        # _bucket_rows pads to a multiple of 256, so the padded flat code
-        # stream always splits into whole 32-code groups for the packer
-        rows_pad = _bucket_rows(rows)
-        xp = x.astype(jnp.float32)
-        if rows_pad != rows:
-            xp = jnp.pad(xp, ((0, rows_pad - rows), (0, 0)))
         codes, scale, zero = _quant_jnp(xp, bits, num_bins, n_steps)
         words = _pack_jnp(codes, bits)
-        nwords = (count * bits + 31) // 32
-        return PackedQuant(words[:nwords], scale[:rows], zero[:rows],
-                           bits, count)
-
-    interpret = impl == "interpret"
-    br = min(block_rows, _round_up(rows, 32))
-    br = _round_up(br, 32)
-    rows_pad = _round_up(rows, br)
-    xp = x.astype(jnp.float32)
-    if rows_pad != rows:
-        xp = jnp.pad(xp, ((0, rows_pad - rows), (0, 0)))
-    words, scale, zero = quant_pack_pallas(
-        xp, bits=bits, num_bins=num_bins, n_steps=n_steps,
-        block_rows=br, interpret=interpret)
-    nwords = (count * bits + 31) // 32
-    return PackedQuant(words[:nwords], scale[:rows], zero[:rows], bits, count)
+    else:
+        words, scale, zero = quant_pack_pallas(
+            xp, bits=bits, num_bins=num_bins, n_steps=n_steps,
+            block_rows=min(block_rows, rows_pad),
+            interpret=impl == "interpret")
+    return PackedQuant(words, np.asarray(scale)[:rows],
+                       np.asarray(zero)[:rows], bits, count)

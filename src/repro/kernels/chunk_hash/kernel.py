@@ -7,15 +7,14 @@ crc32 only covers the payload after it crossed PCIe/host memory).
 
 Mapping: the word stream is viewed as (rows, 128) uint32 lanes; the grid
 tiles rows into (BLOCK_ROWS, 128) VMEM blocks. Each block computes the
-masked partial sum of the per-word mixed terms (see ``ref.py`` — the terms
+masked partial sums of the per-word mixed terms (see ``ref.py`` — the terms
 are position-folded, so the order-sensitive hash still reduces through an
-associative sum and blocks are independent). Partials land in a
-(num_blocks, 1) output; the wrapper sums them and applies the final
-avalanche. One HBM read of the words, O(num_blocks) words written back —
-memory-bound at roofline.
+associative sum and blocks are independent), folded to one (8, 128) tile
+per block. The wrapper sums the tiles mod 2^32 and applies the final
+avalanche. One HBM read of the words, 4 KiB written back per block.
 
-The valid word count rides in as a per-block (1, 1) operand rather than a
-static closure constant, so ragged chunk tails don't fan out into one
+The valid word count rides in through scalar prefetch (SMEM) rather than
+as a static closure constant, so ragged chunk tails don't fan out into one
 compiled kernel per length.
 """
 
@@ -26,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import PRIME1, PRIME2, PRIME3, PRIME5
 
@@ -56,49 +56,55 @@ def finalize(acc: jax.Array, count: jax.Array) -> jax.Array:
 
 
 def chunk_hash_kernel(n_ref, w_ref, out_ref, *, block_rows: int):
-    """One grid block's masked partial sum of mixed terms.
+    """One grid block's masked partial sums of mixed terms.
 
-    n_ref (1, 1) uint32 — the valid word count (replicated per block)
+    n_ref (1,) int32 in SMEM — the valid word count
     w_ref (BLOCK_ROWS, 128) uint32 — this block's slice of the word stream
-    out_ref (1, 1) uint32 — the block's partial sum
+    out_ref (1, 8, 128) int32 — the block's partials, one per (sublane,
+    lane); int32 because Mosaic reduces no unsigned type, and the bits of
+    a wrapping sum are the same either way
     """
     b = pl.program_id(0)
     w = w_ref[...]
-    row = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 1)
-    base = (b * block_rows * LANES).astype(jnp.uint32)
-    idx = base + row * jnp.uint32(LANES) + col
-    t = mix_terms(w, idx)
-    t = jnp.where(idx < n_ref[0, 0], t, jnp.uint32(0))
-    out_ref[0, 0] = jnp.sum(t)
+    row = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    idx = b * (block_rows * LANES) + row * LANES + col
+    t = mix_terms(w, idx.astype(jnp.uint32))
+    t = jnp.where(idx < n_ref[0], t, jnp.uint32(0))
+    t = jax.lax.bitcast_convert_type(t, jnp.int32)
+    out_ref[...] = t.reshape(block_rows // 8, 8, LANES).sum(axis=0)[None]
 
 
-def chunk_hash_pallas(words: jax.Array, count: jax.Array,
-                      block_rows: int = 8,
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def chunk_hash_pallas(words: jax.Array, count, block_rows: int = 0,
                       interpret: bool = False) -> jax.Array:
     """Hash a uint32 word stream on device via the Pallas kernel; returns
     the uint32 hash scalar. ``words`` may be zero-padded past ``count`` —
     padding words are masked out, so the result equals
-    ``ref.hash_words_np(words[:count])``."""
+    ``ref.hash_words_np(words[:count])``. ``block_rows`` (a multiple of 8;
+    0 → up to 512 rows, 64 Ki words, per block) sets the grid tiling."""
+    words = jnp.asarray(words, jnp.uint32)
     n = words.shape[0]
+    if not block_rows:
+        block_rows = min(512, max(8, -(-n // (8 * LANES)) * 8))
     per_block = block_rows * LANES
-    n_pad = ((n + per_block - 1) // per_block) * per_block if n else per_block
+    n_pad = max(per_block, -(-n // per_block) * per_block)
     if n_pad != n:
         words = jnp.pad(words, (0, n_pad - n))
     w2d = words.reshape(-1, LANES)
     num_blocks = w2d.shape[0] // block_rows
     count = jnp.asarray(count, jnp.uint32)
-    nvec = jnp.broadcast_to(count.reshape(1, 1), (num_blocks, 1))
     kernel = functools.partial(chunk_hash_kernel, block_rows=block_rows)
     partials = pl.pallas_call(
         kernel,
-        grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_blocks, 1), jnp.uint32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_blocks,),
+            in_specs=[pl.BlockSpec((block_rows, LANES), lambda i, n: (i, 0))],
+            out_specs=pl.BlockSpec((1, 8, LANES), lambda i, n: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((num_blocks, 8, LANES), jnp.int32),
         interpret=interpret,
-    )(nvec, w2d)
+    )(count.astype(jnp.int32).reshape(1), w2d)
+    partials = jax.lax.bitcast_convert_type(partials, jnp.uint32)
     return finalize(jnp.sum(partials, dtype=jnp.uint32), count)

@@ -9,9 +9,10 @@ packed stream's tail bits beyond the payload are zero — the byte
 equivalence ``tests/test_chunk_hash.py`` pins for bits 1–8 × both quant
 methods.
 
-Word counts are padded to power-of-two buckets (min 1024) so ragged
-incremental chunk tails share a handful of jit cache entries; padding
-words are masked out inside the hash, not mixed in.
+The words are hashed as they come, padding included: the valid count is
+a traced argument and the words past it are masked out inside the hash,
+so the bucketed word arrays ``quant_pack`` emits share a few compiled
+programs.
 """
 
 from __future__ import annotations
@@ -26,20 +27,6 @@ from .kernel import chunk_hash_pallas, finalize, mix_terms
 from .ref import chunk_hash32, hash_words_np
 
 
-def _backend_is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - uninitialized backend
-        return False
-
-
-def _bucket_words(n: int) -> int:
-    b = 1024
-    while b < n:
-        b <<= 1
-    return b
-
-
 @jax.jit
 def _hash_words_jnp(words_pad: jax.Array, count: jax.Array) -> jax.Array:
     i = jnp.arange(words_pad.shape[0], dtype=jnp.uint32)
@@ -48,26 +35,19 @@ def _hash_words_jnp(words_pad: jax.Array, count: jax.Array) -> jax.Array:
     return finalize(jnp.sum(t, dtype=jnp.uint32), count)
 
 
-def chunk_hash32_device(words, count=None, impl: str = "auto",
-                        block_rows: int = 8) -> int:
+def chunk_hash32_device(words, count=None, impl: str = "auto") -> int:
     """Hash ``words[:count]`` (uint32 stream) on device; returns the Python
     int hash. ``impl``: "auto" (pallas on TPU, jnp elsewhere), "pallas",
     "interpret", "jnp", "ref"."""
     n = int(words.shape[0]) if count is None else int(count)
     if impl == "auto":
-        impl = "pallas" if _backend_is_tpu() else "jnp"
+        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
     if impl == "ref" or n == 0:
         return hash_words_np(np.asarray(words)[:n])
     if impl == "jnp":
-        words = jnp.asarray(words, jnp.uint32)[:n]
-        n_pad = _bucket_words(n)
-        if n_pad != n:
-            words = jnp.pad(words, (0, n_pad - n))
-        return int(_hash_words_jnp(words, jnp.uint32(n)))
-    interpret = impl == "interpret"
-    words = jnp.asarray(words, jnp.uint32)[:n]
-    return int(chunk_hash_pallas(words, n, block_rows=block_rows,
-                                 interpret=interpret))
+        return int(_hash_words_jnp(jnp.asarray(words, jnp.uint32),
+                                   jnp.uint32(n)))
+    return int(chunk_hash_pallas(words, n, interpret=impl == "interpret"))
 
 
 @functools.lru_cache(maxsize=None)

@@ -7,11 +7,25 @@ links.
 
 ``make_production_mesh`` is a function (never a module-level constant) so
 importing this module touches no jax device state.
+
+Every mesh here has ``Auto`` axes: the models annotate shardings with
+``with_sharding_constraint`` and let XLA propagate the rest, which
+``jax.make_mesh``'s default ``Explicit`` axes refuse (a table gather
+without an ``out_sharding`` raises ``ShardingTypeError``).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes — the one mesh constructor of
+    this repository."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,13 +40,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over real local devices (tests / local runs)."""
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[: data * model])
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[: data * model])
 
 
 # v5e hardware constants for the roofline report

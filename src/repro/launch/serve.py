@@ -33,6 +33,10 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from ..configs import get_cell
     from ..core import CheckNRunManager, CheckpointConfig, LocalFSStore
     from ..core import manifest as mf

@@ -35,18 +35,20 @@ def main(argv=None):
     ap.add_argument("--train-hours", type=float, default=24.0)
     args = ap.parse_args(argv)
 
-    import jax
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from ..configs import get_cell
     from ..core import CheckpointConfig, InMemoryStore, LocalFSStore, PAPER_DEFAULTS
     from ..core.bitwidth import BitwidthController
     from ..train.loop import SimulatedFailure, Trainer, TrainerConfig
+    from .mesh import make_host_mesh
 
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"),
-                             devices=jax.devices()[: d * m])
+        mesh = make_host_mesh(d, m)
 
     bundle = get_cell(args.arch, args.shape, mesh=mesh, reduced=args.reduced)
     if bundle.kind != "train":

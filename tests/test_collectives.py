@@ -50,7 +50,8 @@ def test_ef_allreduce_multidevice_subprocess():
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.collectives import ef_allreduce_shardmap, init_residuals
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(4, 128)).astype(np.float32))
         res = jnp.zeros((4, 128), jnp.float32)

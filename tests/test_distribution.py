@@ -44,9 +44,10 @@ def test_ep_moe_equals_dense_dispatch():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np, dataclasses, jax.numpy as jnp
         from repro.dist.sharding import lm_rules
+        from repro.launch.mesh import make_host_mesh
         from repro.models import transformer as m_tf
         from repro.models.layers import MoEConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(2, 4)
         rules = lm_rules(mesh)
         cfg_ep = m_tf.TransformerConfig(
             name="t", n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
@@ -75,8 +76,9 @@ def test_sharded_dimenet_equals_plain():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np, jax.numpy as jnp
         from repro.dist.sharding import gnn_rules
+        from repro.launch.mesh import make_host_mesh
         from repro.models import dimenet as m_dn
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(2, 4)
         rules = gnn_rules(mesh)
         cfg = m_dn.DimeNetConfig(name="t", n_blocks=2, d_hidden=16,
                                  n_bilinear=2, n_spherical=3, n_radial=2,
@@ -111,7 +113,8 @@ def test_sharded_train_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_cell
         from repro.data.cells import batch_for_cell
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(2, 2)
         b1 = get_cell("dlrm-rm2", "train_batch", reduced=True)
         bm = get_cell("dlrm-rm2", "train_batch", mesh=mesh, reduced=True)
         batch = batch_for_cell(b1, 0)

@@ -68,21 +68,46 @@ def test_fused_payload_ragged_shapes(rows, dim):
 @pytest.mark.parametrize("bits", [1, 2, 3, 4, 5, 6, 7, 8])
 def test_fused_kernel_interpret_matches_jnp(method, bits):
     """The Pallas fused kernel (interpret mode) and the jnp device path
-    implement the same search + the same word layout: payloads must decode
-    to near-identical codes (f32 rounding ties only) and identical bytes
-    whenever the codes agree."""
+    trace the same quantizer and emit the same word layout: payloads and
+    scale/zero must be byte-identical (the on-chip smoke asserts the same
+    between the compiled kernel and XLA)."""
     x = _rows(256, 64)
     pk = quant_pack(x, bits=bits, method=method, impl="interpret")
     pj = quant_pack(x, bits=bits, method=method, impl="jnp")
-    ck = packing.unpack_bits(
-        packing.words_to_payload(np.asarray(pk.words), pk.count, bits),
-        bits, pk.count)
-    cj = packing.unpack_bits(
-        packing.words_to_payload(np.asarray(pj.words), pj.count, bits),
-        bits, pj.count)
-    assert np.mean(ck != cj) < 2e-3  # round-to-even boundary ties only
-    np.testing.assert_allclose(np.asarray(pk.scale), np.asarray(pj.scale),
-                               rtol=1e-5, atol=1e-7)
+    assert (packing.words_to_payload(np.asarray(pk.words), pk.count, bits)
+            == packing.words_to_payload(np.asarray(pj.words), pj.count, bits))
+    assert pk.scale.tobytes() == pj.scale.tobytes()
+    assert pk.zero.tobytes() == pj.zero.tobytes()
+
+
+@pytest.mark.parametrize("bits", [1, 2, 3, 4, 5, 6, 7, 8])
+@pytest.mark.parametrize("dim", [64, 32, 128])
+def test_pack_row_words_matches_stream_packer(bits, dim):
+    """The kernel's in-VMEM row packer (two bf16 MXU products, no lane
+    reshape) emits exactly the stream packer's words wherever rows own
+    whole words — including codes that straddle 16-bit halves."""
+    from repro.kernels.adaptive_quant.kernel import (pack_codes_u32,
+                                                     pack_row_words,
+                                                     row_pack_weights)
+    codes = RNG.integers(0, 1 << bits, size=(96, dim)).astype(np.int32)
+    codes[0] = (1 << bits) - 1          # all-ones row: every bit set
+    w_low, w_high = row_pack_weights(dim, bits)
+    rows = np.asarray(pack_row_words(jnp.asarray(codes), bits, w_low,
+                                     w_high)).view(np.uint32)
+    stream = np.asarray(pack_codes_u32(
+        jnp.asarray(codes.reshape(-1).astype(np.uint32)), bits))
+    np.testing.assert_array_equal(rows.reshape(-1), stream)
+    assert (packing.words_to_payload(stream, codes.size, bits)
+            == packing.pack_bits_reference(codes.astype(np.uint8), bits))
+
+
+def test_recip_is_accurate():
+    """The divide-free reciprocal the quantizer uses in both compilers."""
+    from repro.kernels.adaptive_quant.kernel import _recip
+    y = np.concatenate([np.geomspace(1e-30, 1e30, 4001),
+                        RNG.uniform(0.5, 2.0, 1000)]).astype(np.float32)
+    got = np.asarray(_recip(jnp.asarray(y)))
+    np.testing.assert_allclose(got, 1.0 / y.astype(np.float64), rtol=3e-7)
 
 
 def test_fused_kernel_interpret_ragged_blocks():

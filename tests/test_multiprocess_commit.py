@@ -341,3 +341,14 @@ def test_commit_once_rejects_divergent_manifest(tiny_snapshot):
     with pytest.raises(CommitRaceError):
         commit_once(store, man)
     mgr.close()
+
+
+def test_host_processes_stay_off_the_accelerator(monkeypatch):
+    """A chip belongs to one process and the launching trainer holds it:
+    every host process is started on the CPU backend, whatever the
+    launcher's own JAX_PLATFORMS says."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = host_proc.child_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    src = env["PYTHONPATH"].split(os.pathsep)[0]
+    assert os.path.isdir(os.path.join(src, "repro"))

@@ -54,7 +54,8 @@ def test_reduced_sharded_train_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_cell
         from repro.data.cells import batch_for_cell
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(2, 2)
         b1 = get_cell("dlrm-rm2", "train_batch", reduced=True)
         bm = get_cell("dlrm-rm2", "train_batch", mesh=mesh, reduced=True)
         batch = batch_for_cell(b1, 0)

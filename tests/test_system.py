@@ -16,6 +16,7 @@ from repro.configs import get_cell
 from repro.core import CheckpointConfig, InMemoryStore, PAPER_DEFAULTS
 from repro.data.cells import batch_for_cell
 from repro.launch.dryrun import collective_bytes
+from repro.launch.mesh import make_host_mesh
 from repro.train.loop import Trainer, TrainerConfig
 
 
@@ -45,7 +46,7 @@ def test_sharded_train_and_cross_mesh_restore():
 def test_mini_dryrun_lower_and_collectives():
     """A miniature of the production dry-run: lower + compile a train step
     for a 1×1 mesh and parse the collective inventory from the HLO."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     b = get_cell("bert4rec", "train_batch", mesh=mesh, reduced=True)
     state_shapes = b.state_shapes()
     sh = jax.tree.map(lambda p: NamedSharding(mesh, p if p is not None else P()),
