@@ -100,7 +100,9 @@ def main():
     print(f"\nmodel {model_bytes/1e6:.0f} MB | wrote {stats['bytes_written']/1e6:.0f} MB "
           f"for ~{n_ckpts} checkpoints → {model_bytes*n_ckpts/stats['bytes_written']:.1f}× "
           f"bandwidth reduction vs fp32 fulls")
-    print(f"snapshot stall: {stall:.2f}s of {wall:.1f}s total "
+    # stall_times: each checkpoint() whole, the snapshot and the wait for
+    # the previous save
+    print(f"checkpoint stall: {stall:.2f}s of {wall:.1f}s total "
           f"({100*stall/wall:.2f}% — paper target <0.4%)")
     t2.close()
 

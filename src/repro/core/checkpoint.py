@@ -31,6 +31,7 @@ memory is O(pipeline window), not O(checkpoint).
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import os
@@ -45,6 +46,7 @@ import numpy as np
 from . import manifest as mf
 from . import packing
 from . import range_reader as rr
+from . import trace
 from . import tracker
 from .bitwidth import BitwidthController
 from .coordinator import CommitContext
@@ -163,6 +165,16 @@ class RestoredState:
     # caller ASKED for (corrupt); ``step`` is the older chain actually
     # restored — callers must treat the gap as lost training to redo
     degraded_from: Optional[int] = None
+    # request id of the restore's spans (repro.core.trace), so the caller's
+    # placement span joins them
+    request: Optional[int] = None
+
+    def nbytes(self) -> int:
+        """Bytes of the restored host arrays (what placement copies up)."""
+        return (sum(t.nbytes for t in self.tables.values())
+                + sum(a.nbytes for d in self.row_state.values()
+                      for a in d.values())
+                + sum(np.asarray(a).nbytes for a in self.dense.values()))
 
 
 class PartialRecoveryError(ValueError):
@@ -194,22 +206,6 @@ class PartialRecoveryError(ValueError):
         super().__init__(
             f"partial recovery of host {host} at step {step} "
             f"unavailable ({kind}): {detail}")
-
-
-class _QuantClock:
-    """Thread-safe accumulator for device quantize(+pack) seconds — the
-    encode stage runs quantization on several workers, so per-chunk timings
-    need a shared sink."""
-
-    __slots__ = ("seconds", "_lock")
-
-    def __init__(self) -> None:
-        self.seconds = 0.0
-        self._lock = threading.Lock()
-
-    def add(self, dt: float) -> None:
-        with self._lock:
-            self.seconds += dt
 
 
 class CheckNRunManager:
@@ -280,17 +276,21 @@ class CheckNRunManager:
     # ------------------------------------------------------------------ save
     def save(self, snap: Snapshot, block: bool = False) -> Future:
         """Submit a snapshot for background checkpointing. Enforces the
-        paper's non-overlap rule: wait for, or cancel, the in-flight write."""
-        if self._inflight is not None and not self._inflight.done():
-            if self.config.overlap == "cancel":
-                self._cancel.set()
-                try:
-                    self._inflight.result()
-                except Exception:
-                    pass
-            else:
-                self._inflight.result()  # wait ("complete") — paper default
+        paper's non-overlap rule: wait for, or cancel, the in-flight write
+        (the ``cnr.save.wait`` span). The write runs in a ``cnr.save`` span
+        under the caller's current span."""
+        with trace.span("cnr.save.wait", request=snap.step, step=snap.step):
+            if self._inflight is not None and not self._inflight.done():
+                if self.config.overlap == "cancel":
+                    self._cancel.set()
+                    try:
+                        self._inflight.result()
+                    except Exception:
+                        pass
+                else:
+                    self._inflight.result()  # wait ("complete") — paper default
         self._cancel = threading.Event()
+        self._count(snapshot_bytes_total=snap.copied_bytes())
 
         with self._lock:
             for name, t in snap.touched.items():
@@ -304,7 +304,9 @@ class CheckNRunManager:
 
         cancel = self._cancel
         if self.config.async_write and not block:
-            fut = self._pool.submit(self._write_guarded, snap, cum, unc, cancel)
+            fut = self._pool.submit(contextvars.copy_context().run,
+                                    self._write_guarded, snap, cum, unc,
+                                    cancel)
         else:
             fut: Future = Future()
             try:
@@ -331,8 +333,11 @@ class CheckNRunManager:
 
     # ------------------------------------------------------------- internals
     def _write_guarded(self, snap, cum, unc, cancel) -> SaveResult:
+        sp = trace.span("cnr.save", request=snap.step, step=snap.step)
         try:
-            res = self._write(snap, cum, unc, cancel)
+            with sp:
+                res = self._write(snap, cum, unc, cancel)
+                sp.set(kind=res.kind, bytes=res.nbytes)
         except CheckpointCancelled:
             self._aborted_steps.add(snap.step)
             self._count(saves_total=1, saves_cancelled=1)
@@ -343,6 +348,8 @@ class CheckNRunManager:
             self._count(saves_total=1, saves_failed=1)
             traceback.print_exc()
             raise
+        finally:
+            self._count(compiles_total=sp.compiles)
         self._count(saves_total=1, saves_ok=1, save_bytes_total=res.nbytes,
                     last_success_step=res.step, last_success_unix=time.time(),
                     last_save_kind=res.kind,
@@ -443,9 +450,7 @@ class CheckNRunManager:
     def _submit_table_chunks(self, pipe: WritePipeline, name: str,
                              tab: np.ndarray, sel: np.ndarray, aux,
                              qcfg: Optional[QuantConfig], full: bool,
-                             key_prefix: str,
-                             clock: Optional[_QuantClock] = None
-                             ) -> List[Future]:
+                             key_prefix: str) -> List[Future]:
         """Stage 0 (writer/host thread): slice the selection into chunks and
         submit one encode→write job per chunk. Quantization happens INSIDE
         the encode jobs (one fused dispatch per chunk), so it parallelizes
@@ -453,17 +458,17 @@ class CheckNRunManager:
         feeds the window. The ONE implementation of the chunk byte format's
         emission — single-host and per-host shard writers both go through
         here (key_prefix is the only difference), which is what keeps their
-        restores byte-identical. Returns the chunk futures; device quantize
-        seconds accumulate into ``clock``."""
+        restores byte-identical. Returns the chunk futures."""
         cfg = self.config
         futs: List[Future] = []
         for seq, blo in enumerate(range(0, len(sel), cfg.chunk_rows)):
             idx = sel[blo: blo + cfg.chunk_rows]
             key = f"{key_prefix}{name}/{seq:06d}.bin"
             encode_fn = functools.partial(
-                self._encode_chunk_job, key, tab, idx, aux, qcfg, full, clock)
+                self._encode_chunk_job, key, tab, idx, aux, qcfg, full)
             write_fn = functools.partial(self.store.put, key)
-            futs.append(pipe.submit(encode_fn, write_fn))
+            futs.append(pipe.submit(encode_fn, write_fn,
+                                    attrs=dict(rows=len(idx))))
         return futs
 
     def _make_table_record(self, rows: int, dim: int, dtype: str, aux,
@@ -492,7 +497,6 @@ class CheckNRunManager:
                     if cfg.write_deadline_s else None)
         pipe = self._make_pipeline(cancel, deadline)
 
-        clock = _QuantClock()
         table_futs: Dict[str, List[Future]] = {}
         table_shape: Dict[str, Tuple[int, int, str, Dict[str, np.ndarray]]] = {}
         dense_futs: Dict[str, Future] = {}
@@ -503,7 +507,7 @@ class CheckNRunManager:
                 aux = snap.row_state.get(name, {})
                 table_futs[name] = self._submit_table_chunks(
                     pipe, name, tab, sel, aux, qcfg, decision == "full",
-                    mf.chunk_prefix(step), clock)
+                    mf.chunk_prefix(step))
                 table_shape[name] = (rows, dim, str(tab.dtype), aux)
 
             for key_name, arr in snap.dense.items():
@@ -518,49 +522,51 @@ class CheckNRunManager:
             pipe.close()
 
         # All futures settled successfully — assemble the manifest in
-        # deterministic submission order and commit atomically.
-        tables: Dict[str, mf.TableRecord] = {}
-        total_bytes = 0
-        for name, futs in table_futs.items():
-            rows, dim, dtype, aux = table_shape[name]
-            chunks = [f.result() for f in futs]
-            total_bytes += sum(c.nbytes for c in chunks)
-            tables[name] = self._make_table_record(rows, dim, dtype, aux,
-                                                   qcfg, chunks)
-        dense: Dict[str, mf.DenseRecord] = {}
-        for key_name, fut in dense_futs.items():
-            dense[key_name] = fut.result()
-            total_bytes += dense[key_name].nbytes
+        # deterministic submission order and commit atomically, then the
+        # post-commit bookkeeping (all in the cnr.save.commit span).
+        with trace.span("cnr.save.commit"):
+            tables: Dict[str, mf.TableRecord] = {}
+            total_bytes = 0
+            for name, futs in table_futs.items():
+                rows, dim, dtype, aux = table_shape[name]
+                chunks = [f.result() for f in futs]
+                total_bytes += sum(c.nbytes for c in chunks)
+                tables[name] = self._make_table_record(rows, dim, dtype, aux,
+                                                       qcfg, chunks)
+            dense: Dict[str, mf.DenseRecord] = {}
+            for key_name, fut in dense_futs.items():
+                dense[key_name] = fut.result()
+                total_bytes += dense[key_name].nbytes
 
-        prev = mf.latest_step(self.store)
-        base = (step if decision == "full" else self.policy.state.baseline_step)
-        stats = pipe.stats
-        man = mf.Manifest(
-            step=step, kind=decision, base_step=base,
-            prev_step=prev, quant=(dataclasses.asdict(qcfg) if qcfg else None),
-            policy=self.policy.to_dict() | {"name": self.policy.name},
-            tables=tables, dense=dense,
-            extra=snap.extra | {"bitwidth": self.bitwidth.to_dict() if self.bitwidth else None},
-            nbytes_total=total_bytes,
-            wall_time_s=time.monotonic() - t_start,
-            created_unix=time.time(),
-            layout=mf.make_layout(1),
-            delta=build_delta(tables, dense))
-        mf.commit(self.store, man)
+            prev = mf.latest_step(self.store)
+            base = (step if decision == "full" else self.policy.state.baseline_step)
+            stats = pipe.stats
+            man = mf.Manifest(
+                step=step, kind=decision, base_step=base,
+                prev_step=prev, quant=(dataclasses.asdict(qcfg) if qcfg else None),
+                policy=self.policy.to_dict() | {"name": self.policy.name},
+                tables=tables, dense=dense,
+                extra=snap.extra | {"bitwidth": self.bitwidth.to_dict() if self.bitwidth else None},
+                nbytes_total=total_bytes,
+                wall_time_s=time.monotonic() - t_start,
+                created_unix=time.time(),
+                layout=mf.make_layout(1),
+                delta=build_delta(tables, dense))
+            mf.commit(self.store, man)
 
-        self._post_commit(step, decision, total_bytes)
-        return SaveResult(
-            step=step, kind=decision, nbytes=total_bytes,
-            # quantization runs inside the encode stage now, so its busy
-            # seconds are a SUBSET of encode_busy_s (quantize_s reports it)
-            build_time_s=stats.encode_busy_s,
-            write_time_s=stats.write_busy_s,
-            pipeline_stats=dict(
-                items=stats.items, payload_bytes=stats.payload_bytes,
-                encode_busy_s=stats.encode_busy_s,
-                write_busy_s=stats.write_busy_s,
-                quantize_s=clock.seconds, wall_s=stats.wall_s,
-                occupancy=pipe.occupancy()))
+            self._post_commit(step, decision, total_bytes)
+            return SaveResult(
+                step=step, kind=decision, nbytes=total_bytes,
+                # quantization runs inside the encode stage, so its busy
+                # seconds (cnr.save.quant spans) are a SUBSET of encode_busy_s
+                build_time_s=stats.encode_busy_s,
+                write_time_s=stats.write_busy_s,
+                pipeline_stats=dict(
+                    items=stats.items, payload_bytes=stats.payload_bytes,
+                    encode_busy_s=stats.encode_busy_s,
+                    write_busy_s=stats.write_busy_s,
+                    wall_s=stats.wall_s,
+                    occupancy=pipe.occupancy()))
 
     def _post_commit(self, step: int, decision: str, nbytes: int) -> None:
         """Bookkeeping once the manifest is durable: advance the policy,
@@ -693,8 +699,6 @@ class CheckNRunManager:
         per_host = [w.stats for w in writers]
         return SaveResult(
             step=step, kind=decision, nbytes=man.nbytes_total,
-            # quantize_s is a subset of encode_busy_s (quant runs inside
-            # the encode stage), so it is NOT added on top
             build_time_s=sum(s["encode_busy_s"] for s in per_host),
             write_time_s=sum(s["write_busy_s"] for s in per_host),
             pipeline_stats=dict(
@@ -703,7 +707,6 @@ class CheckNRunManager:
                 payload_bytes=sum(s["payload_bytes"] for s in per_host),
                 encode_busy_s=sum(s["encode_busy_s"] for s in per_host),
                 write_busy_s=sum(s["write_busy_s"] for s in per_host),
-                quantize_s=sum(s["quantize_s"] for s in per_host),
                 wall_s=time.monotonic() - t_start,
                 per_host=per_host))
 
@@ -904,9 +907,9 @@ class CheckNRunManager:
             time.sleep(0.02)
 
     # ---------------------------------------------------------- encode stage
-    def _encode_chunk_job(self, key: str, tab, idx, aux, qcfg, full, clock):
+    def _encode_chunk_job(self, key: str, tab, idx, aux, qcfg, full):
         payload, sections, hash32 = self._encode_chunk(tab, idx, aux, qcfg,
-                                                       full, clock)
+                                                       full)
         row_range = ([int(idx[0]), int(idx[-1]) + 1]
                      if full and len(idx) else None)
         # incremental chunks record compressed global-row spans — the delta
@@ -929,7 +932,7 @@ class CheckNRunManager:
 
     def _encode_chunk(self, tab: np.ndarray, idx: np.ndarray,
                       aux: Dict[str, np.ndarray], qcfg: Optional[QuantConfig],
-                      full: bool, clock: Optional[_QuantClock] = None):
+                      full: bool):
         """Serialize one chunk of rows: [indices?][scale][zero][codes][aux...]
         (full-checkpoint chunks are contiguous → range-encoded, no indices).
         Returns (payload, sections, hash32) — hash32 covers the PRIMARY
@@ -956,11 +959,9 @@ class CheckNRunManager:
             # full-checkpoint chunks are ascending ranges → contiguous view
             rows_arr = (tab[int(idx[0]):int(idx[-1]) + 1] if full
                         else tab[idx])
-            t0 = time.monotonic()
-            scale, zero, codes_payload, hash32 = self._quant_encode(rows_arr,
-                                                                    qcfg)
-            if clock is not None:
-                clock.add(time.monotonic() - t0)
+            with trace.span("cnr.save.quant", rows=len(idx)):
+                scale, zero, codes_payload, hash32 = self._quant_encode(
+                    rows_arr, qcfg)
             # fp16 quantization metadata (beyond-paper: the paper flags its
             # metadata structure as unoptimized; fp16 scale/zero costs <1e-3
             # relative dequant error and halves the per-row overhead)
@@ -1015,13 +1016,21 @@ class CheckNRunManager:
             step = mf.latest_step(store)
         if step is None:
             raise FileNotFoundError("no valid checkpoint found")
+        sp = trace.span("cnr.restore", request=trace.new_request(), step=step)
         try:
-            return self._restore_at(step)
-        except ChunkCorruptionError as e:
-            self._count(corruption_errors_total=1)
-            if on_corruption != "fallback":
-                raise
-            return self._restore_fallback(step, e)
+            with sp:
+                try:
+                    out = self._restore_at(step)
+                except ChunkCorruptionError as e:
+                    self._count(corruption_errors_total=1)
+                    if on_corruption != "fallback":
+                        raise
+                    out = self._restore_fallback(step, e)
+                sp.set(chain_len=out.chain_len)
+        finally:
+            self._count(compiles_total=sp.compiles)
+        out.request = sp.request
+        return out
 
     def _restore_fallback(self, target: int,
                           first_err: ChunkCorruptionError) -> RestoredState:
@@ -1358,13 +1367,15 @@ class CheckNRunManager:
                     decode,
                     functools.partial(self._apply_decoded, tables[name],
                                       row_state[name], rec, ch,
-                                      offsets[name]))
+                                      offsets[name]),
+                    attrs=dict(rows=ch.n_rows, bytes=ch.nbytes))
             for key_name, drec in final_man.dense.items():
                 pipe.submit(
                     functools.partial(self.store.get, drec.key),
                     functools.partial(self._decode_dense, final_man.step,
                                       key_name, drec),
-                    functools.partial(dense.__setitem__, key_name))
+                    functools.partial(dense.__setitem__, key_name),
+                    attrs=dict(bytes=drec.nbytes))
             pipe.drain()
         finally:
             pipe.close()

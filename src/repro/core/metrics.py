@@ -45,6 +45,10 @@ class ManagerMetrics:
     last_success_step: Optional[int] = None
     last_success_unix: Optional[float] = None
     last_save_kind: Optional[str] = None
+    # device→host bytes of the snapshots handed to save(), and backend
+    # compiles inside the manager's save and restore spans (repro.core.trace)
+    snapshot_bytes_total: int = 0
+    compiles_total: int = 0
     # restores
     restores_total: int = 0
     restore_bytes_total: int = 0
@@ -100,6 +104,10 @@ def _prom_escape(v: str) -> str:
 _HELP = {
     "saves_total": "Checkpoint save attempts by outcome.",
     "save_bytes_total": "Payload bytes committed by successful saves.",
+    "snapshot_bytes_total":
+        "Device-to-host bytes of the snapshots handed to saves.",
+    "compiles_total":
+        "Backend compiles inside the manager's save and restore spans.",
     "last_success_step": "Step of the last committed checkpoint.",
     "last_success_age_s": "Seconds since the last committed checkpoint.",
     "restores_total": "Completed restores.",
@@ -209,7 +217,8 @@ def render_prometheus(values: dict, prefix: str = PROM_PREFIX) -> str:
              {"kind": "full"}, "counter")
         emit("recoveries_total", values.get("recoveries_resharded_total"),
              {"kind": "resharded"}, "counter")
-    for name in ("save_bytes_total", "restores_total", "restore_bytes_total",
+    for name in ("save_bytes_total", "snapshot_bytes_total", "compiles_total",
+                 "restores_total", "restore_bytes_total",
                  "restore_fallbacks_total", "corruption_errors_total",
                  "recovery_rows_replayed_total",
                  "retention_steps_deleted_total", "gc_steps_reclaimed_total",

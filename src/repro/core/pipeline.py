@@ -30,8 +30,12 @@ Shared semantics:
   failed item also advances the ordered-apply sequence), and resurfaces as
   that item's Future exception and from :meth:`drain`.
 
-Per-stage busy-time accounting feeds the occupancy metrics in
-``benchmarks/write_path.py``.
+Each stage call runs inside a program span ``<span_prefix>.<stage>``
+(``cnr.save.encode`` ... ``cnr.restore.apply``; ``repro.core.trace``)
+under the span that submitted the item, with the item's attributes; the
+span's two clock reads feed the per-stage busy seconds, and with them the
+occupancy that ``ManagerMetrics`` exports and ``RestoredState.stats``
+carries.
 """
 
 from __future__ import annotations
@@ -41,6 +45,7 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from . import trace
 from .storage import CheckpointCancelled
 
 
@@ -69,13 +74,16 @@ class PipelineStats:
 
 
 class _Item:
-    __slots__ = ("seq", "fns", "value", "future")
+    __slots__ = ("seq", "fns", "value", "future", "parent", "attrs")
 
-    def __init__(self, seq: int, fns: Sequence[Callable]):
+    def __init__(self, seq: int, fns: Sequence[Callable],
+                 parent: Optional[trace.Span], attrs: Dict[str, int]):
         self.seq = seq
         self.fns = fns
         self.value: Any = None
         self.future: Future = Future()
+        self.parent = parent
+        self.attrs = attrs
 
 
 class StagePipeline:
@@ -86,9 +94,11 @@ class StagePipeline:
                  cancel: Optional[threading.Event] = None,
                  deadline: Optional[float] = None,
                  ordered_final: bool = False,
-                 name_prefix: str = "cnr") -> None:
+                 name_prefix: str = "cnr",
+                 span_prefix: str = "cnr.pipeline") -> None:
         assert stages, "need at least one stage"
         self.stage_names = [n for n, _ in stages]
+        self.span_names = [f"{span_prefix}.{n}" for n in self.stage_names]
         self.workers = {n: max(1, w) for n, w in stages}
         if ordered_final:
             # ordering relies on the final pool executing in submission
@@ -136,17 +146,19 @@ class StagePipeline:
             raise CheckpointCancelled("deadline exceeded")
 
     # ------------------------------------------------------------ submission
-    def submit(self, fns: Sequence[Callable]) -> Future:
+    def submit(self, fns: Sequence[Callable],
+               attrs: Optional[Dict[str, int]] = None) -> Future:
         """Queue one item. ``fns[0]()`` runs on stage 0; each later
         ``fns[k](value)`` consumes the previous stage's return value; the
-        Future resolves to the final stage's return value."""
+        Future resolves to the final stage's return value. Each stage runs
+        in a span under the caller's current span, with ``attrs``."""
         assert len(fns) == len(self.stage_names)
         # Bounded window; poll so cancellation/failure interrupts the wait.
         while not self._sem.acquire(timeout=0.05):
             self._check_abort()
         try:
             self._check_abort()
-            item = _Item(self._seq, list(fns))
+            item = _Item(self._seq, list(fns), trace.current(), attrs or {})
             self._seq += 1
             self._items.append(item)
             self._pools[0].submit(self._run_stage, item, 0)
@@ -170,11 +182,11 @@ class StagePipeline:
         last = len(self.stage_names) - 1
         try:
             self._check_abort()
-            t0 = time.monotonic()
-            value = item.fns[k]() if k == 0 else item.fns[k](item.value)
-            dt = time.monotonic() - t0
+            with trace.span(self.span_names[k], parent=item.parent,
+                            **item.attrs) as sp:
+                value = item.fns[k]() if k == 0 else item.fns[k](item.value)
             with self._lock:
-                self.stats.busy[self.stage_names[k]] += dt
+                self.stats.busy[self.stage_names[k]] += sp.seconds
                 if k == last:
                     self.stats.items += 1
         except BaseException as e:
@@ -264,7 +276,8 @@ class WritePipeline(StagePipeline):
     checkpoint write. ``submit(encode_fn, write_fn)``: ``encode_fn() ->
     (payload, result)`` runs on an encode worker; ``write_fn(payload)`` on
     a write worker; the Future resolves to ``result`` once the payload is
-    durably put."""
+    durably put. Stage spans: ``cnr.save.encode`` / ``cnr.save.write``,
+    each with the payload's ``bytes``."""
 
     def __init__(self, encode_workers: int = 2, write_workers: int = 4,
                  max_inflight: Optional[int] = None,
@@ -273,7 +286,7 @@ class WritePipeline(StagePipeline):
         super().__init__([("encode", encode_workers),
                           ("write", write_workers)],
                          max_inflight=max_inflight, cancel=cancel,
-                         deadline=deadline)
+                         deadline=deadline, span_prefix="cnr.save")
 
     @property
     def encode_workers(self) -> int:
@@ -284,19 +297,22 @@ class WritePipeline(StagePipeline):
         return self.workers["write"]
 
     def submit(self, encode_fn: Callable[[], Tuple[bytes, Any]],
-               write_fn: Callable[[bytes], None]) -> Future:
+               write_fn: Callable[[bytes], None],
+               attrs: Optional[Dict[str, int]] = None) -> Future:
         def enc():
             payload, result = encode_fn()
+            trace.annotate(bytes=len(payload))
             with self._lock:
                 self.stats.payload_bytes += len(payload)
             return payload, result
 
         def wr(value):
             payload, result = value
+            trace.annotate(bytes=len(payload))
             write_fn(payload)
             return result
 
-        return super().submit([enc, wr])
+        return super().submit([enc, wr], attrs)
 
 
 class RestorePipeline(StagePipeline):
@@ -305,18 +321,20 @@ class RestorePipeline(StagePipeline):
     submission (= chain replay) order so a later manifest's rows always
     overwrite an earlier one's. ``submit(fetch_fn, decode_fn, apply_fn)``:
     ``fetch_fn() -> bytes``, ``decode_fn(bytes) -> decoded``,
-    ``apply_fn(decoded) -> result``."""
+    ``apply_fn(decoded) -> result``. Stage spans: ``<span_prefix>.fetch``
+    (with the fetched ``bytes``), ``.decode``, ``.apply``."""
 
     def __init__(self, fetch_workers: int = 4, decode_workers: int = 2,
                  max_inflight: Optional[int] = None,
                  cancel: Optional[threading.Event] = None,
-                 deadline: Optional[float] = None) -> None:
+                 deadline: Optional[float] = None,
+                 span_prefix: str = "cnr.restore") -> None:
         super().__init__([("fetch", fetch_workers),
                           ("decode", decode_workers),
                           ("apply", 1)],
                          max_inflight=max_inflight, cancel=cancel,
                          deadline=deadline, ordered_final=True,
-                         name_prefix="cnr-restore")
+                         name_prefix="cnr-restore", span_prefix=span_prefix)
 
     @property
     def fetch_workers(self) -> int:
@@ -328,11 +346,13 @@ class RestorePipeline(StagePipeline):
 
     def submit(self, fetch_fn: Callable[[], bytes],
                decode_fn: Callable[[bytes], Any],
-               apply_fn: Callable[[Any], Any]) -> Future:
+               apply_fn: Callable[[Any], Any],
+               attrs: Optional[Dict[str, int]] = None) -> Future:
         def fetch():
             data = fetch_fn()
+            trace.annotate(bytes=len(data))
             with self._lock:
                 self.stats.payload_bytes += len(data)
             return data
 
-        return super().submit([fetch, decode_fn, apply_fn])
+        return super().submit([fetch, decode_fn, apply_fn], attrs)

@@ -34,6 +34,7 @@ part manifests written here are what ``ckpt scan`` classifies as benign
 
 from __future__ import annotations
 
+import contextvars
 import functools
 import time
 import zlib
@@ -184,12 +185,9 @@ class HostShardWriter:
         (``_submit_table_chunks`` / ``_make_table_record``) — the host key
         prefix and the row-range selection are the only differences from the
         single-host path, which is what keeps restores byte-identical."""
-        from ..core.checkpoint import _QuantClock
-
         step = snap.step
         full = decision == "full"
         prefix = mf.chunk_host_prefix(step, self.host)
-        clock = _QuantClock()
         pipe = self.enc._make_pipeline(self.cancel, self.deadline)
         table_futs: Dict[str, list] = {}
         table_shape: Dict[str, tuple] = {}
@@ -202,7 +200,7 @@ class HostShardWriter:
                                             row_range=(lo, hi))
                 aux = snap.row_state.get(name, {})
                 table_futs[name] = self.enc._submit_table_chunks(
-                    pipe, name, tab, sel, aux, qcfg, full, prefix, clock)
+                    pipe, name, tab, sel, aux, qcfg, full, prefix)
                 table_shape[name] = (rows, dim, str(tab.dtype), aux)
 
             for key_name, arr in snap.dense.items():
@@ -247,7 +245,7 @@ class HostShardWriter:
         st = pipe.stats
         self.stats = dict(
             host=self.host, items=st.items, payload_bytes=st.payload_bytes,
-            quantize_s=clock.seconds, encode_busy_s=st.encode_busy_s,
+            encode_busy_s=st.encode_busy_s,
             write_busy_s=st.write_busy_s, wall_s=st.wall_s,
             occupancy=pipe.occupancy())
         return part
@@ -313,7 +311,9 @@ def run_host_writers(writers: List[HostShardWriter], snap, decision: str,
 
     with ThreadPoolExecutor(max_workers=len(writers),
                             thread_name_prefix="cnr-host") as pool:
-        futs = [pool.submit(guarded, w) for w in writers]
+        # each host's pipeline spans join the caller's (repro.core.trace)
+        futs = [pool.submit(contextvars.copy_context().run, guarded, w)
+                for w in writers]
         excs = [f.exception() for f in futs]
     root = None
     root_host = None

@@ -76,17 +76,22 @@ def dot_interaction(feats: jax.Array) -> jax.Array:
 def _logits(params, dense_x, sparse_ids, cfg: DLRMConfig, rules: ShardingRules,
             vectors=None):
     cd = cfg.compute_dtype
-    bot = mlp_apply(params["dense"]["bot"], dense_x, final_act=True, compute_dtype=cd)
-    if vectors is not None:
-        emb = vectors.sum(axis=2)                              # (B, F, D)
-        emb = rules.shard(emb, "batch", None, None)
-    else:
-        emb = lookup_fields(params["tables"], sparse_ids, rules)  # (B, F, D)
-    feats = jnp.concatenate([bot[:, None, :], emb.astype(cd)], axis=1)
-    feats = rules.shard(feats, "batch", None, None)
-    inter = dot_interaction(feats)
-    top_in = jnp.concatenate([bot, inter], axis=-1)
-    out = mlp_apply(params["dense"]["top"], top_in, compute_dtype=cd)
+    with jax.named_scope("mlp"):
+        bot = mlp_apply(params["dense"]["bot"], dense_x, final_act=True,
+                        compute_dtype=cd)
+    with jax.named_scope("lookup"):
+        if vectors is not None:
+            emb = vectors.sum(axis=2)                          # (B, F, D)
+            emb = rules.shard(emb, "batch", None, None)
+        else:
+            emb = lookup_fields(params["tables"], sparse_ids, rules)  # (B, F, D)
+    with jax.named_scope("interaction"):
+        feats = jnp.concatenate([bot[:, None, :], emb.astype(cd)], axis=1)
+        feats = rules.shard(feats, "batch", None, None)
+        inter = dot_interaction(feats)
+    with jax.named_scope("mlp"):
+        top_in = jnp.concatenate([bot, inter], axis=-1)
+        out = mlp_apply(params["dense"]["top"], top_in, compute_dtype=cd)
     return out[..., 0].astype(jnp.float32)
 
 
@@ -110,6 +115,11 @@ def make_sparse_train_step(cfg: DLRMConfig, rules: ShardingRules, dense_opt,
     dedup-aggregated (sort + segment-sum) and scattered back with exact
     row-wise-AdaGrad semantics — HBM traffic scales with touched rows, not
     table rows (≈500× less for the train_batch cell).
+
+    Named scopes mark the step's phases in a profile: ``lookup``,
+    ``backward`` (the loss and its gradient; the forward's own ``mlp`` and
+    ``interaction`` scopes nest inside it), ``dense_update`` and
+    ``sparse_update`` (the row-wise AdaGrad scatter).
     """
     import jax
 
@@ -132,41 +142,45 @@ def make_sparse_train_step(cfg: DLRMConfig, rules: ShardingRules, dense_opt,
 
     def train_step(state: TrainState, batch):
         ids = batch["sparse_ids"]                             # (B,F,H)
-        vectors = gather_vectors(state.params["tables"], ids)
-        (loss, acc_m), (g_dense, g_vec) = jax.value_and_grad(
-            loss_from, argnums=(0, 1), has_aux=True)(
-                state.params["dense"], vectors, batch)
+        with jax.named_scope("lookup"):
+            vectors = gather_vectors(state.params["tables"], ids)
+        with jax.named_scope("backward"):
+            (loss, acc_m), (g_dense, g_vec) = jax.value_and_grad(
+                loss_from, argnums=(0, 1), has_aux=True)(
+                    state.params["dense"], vectors, batch)
 
-        d_upd, d_state = dense_opt.update(g_dense, state.opt_state["dense"],
-                                          state.params["dense"])
-        new_dense = apply_updates(state.params["dense"], d_upd)
+        with jax.named_scope("dense_update"):
+            d_upd, d_state = dense_opt.update(
+                g_dense, state.opt_state["dense"], state.params["dense"])
+            new_dense = apply_updates(state.params["dense"], d_upd)
 
         tables = dict(state.params["tables"])
         accs = dict(state.opt_state["tables"])
         touched = dict(state.touched)
-        for f in range(F):
-            name = f"emb_{f}"
-            V = tables[name].shape[0]
-            idf = ids[:, f, :].reshape(-1)                    # (B·H,)
-            g = g_vec[:, f, :, :].reshape(idf.shape[0], -1)   # (B·H, D)
-            order = jnp.argsort(idf)
-            ids_s = idf[order]
-            g_s = jnp.take(g, order, axis=0)
-            first = jnp.concatenate([jnp.ones((1,), bool),
-                                     ids_s[1:] != ids_s[:-1]])
-            seg = jnp.cumsum(first) - 1
-            g_agg = jax.ops.segment_sum(g_s, seg, num_segments=idf.shape[0])
-            g_rows = jnp.where(first[:, None], jnp.take(g_agg, seg, axis=0), 0.0)
-            write_ids = jnp.where(first, ids_s, V)            # V ⇒ dropped
-            acc_rows = jnp.take(accs[name], jnp.minimum(write_ids, V - 1))
-            g2 = jnp.mean(jnp.square(g_rows), axis=-1)
-            new_acc = acc_rows + g2
-            upd = -lr * g_rows / (jnp.sqrt(new_acc)[:, None] + eps)
-            tables[name] = tables[name].at[write_ids].add(
-                upd.astype(tables[name].dtype), mode="drop")
-            accs[name] = accs[name].at[write_ids].set(new_acc, mode="drop")
-            touched[name] = jnp.logical_or(
-                touched[name], jnp.zeros((V,), bool).at[idf].set(True, mode="drop"))
+        with jax.named_scope("sparse_update"):
+            for f in range(F):
+                name = f"emb_{f}"
+                V = tables[name].shape[0]
+                idf = ids[:, f, :].reshape(-1)                    # (B·H,)
+                g = g_vec[:, f, :, :].reshape(idf.shape[0], -1)   # (B·H, D)
+                order = jnp.argsort(idf)
+                ids_s = idf[order]
+                g_s = jnp.take(g, order, axis=0)
+                first = jnp.concatenate([jnp.ones((1,), bool),
+                                         ids_s[1:] != ids_s[:-1]])
+                seg = jnp.cumsum(first) - 1
+                g_agg = jax.ops.segment_sum(g_s, seg, num_segments=idf.shape[0])
+                g_rows = jnp.where(first[:, None], jnp.take(g_agg, seg, axis=0), 0.0)
+                write_ids = jnp.where(first, ids_s, V)            # V ⇒ dropped
+                acc_rows = jnp.take(accs[name], jnp.minimum(write_ids, V - 1))
+                g2 = jnp.mean(jnp.square(g_rows), axis=-1)
+                new_acc = acc_rows + g2
+                upd = -lr * g_rows / (jnp.sqrt(new_acc)[:, None] + eps)
+                tables[name] = tables[name].at[write_ids].add(
+                    upd.astype(tables[name].dtype), mode="drop")
+                accs[name] = accs[name].at[write_ids].set(new_acc, mode="drop")
+                touched[name] = jnp.logical_or(
+                    touched[name], jnp.zeros((V,), bool).at[idf].set(True, mode="drop"))
 
         new_state = TrainState(
             step=state.step + 1,

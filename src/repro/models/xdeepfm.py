@@ -80,13 +80,17 @@ def cin(x0: jax.Array, weights, rules: ShardingRules,
 
 def _logits(params, sparse_ids, cfg: XDeepFMConfig, rules: ShardingRules):
     cd = cfg.compute_dtype
-    emb = lookup_fields(params["tables"], sparse_ids, rules).astype(cd)  # (B,F,D)
-    lin = lookup_fields(params["tables"], sparse_ids, rules, prefix="lin")  # (B,F,1)
+    with jax.named_scope("lookup"):
+        emb = lookup_fields(params["tables"], sparse_ids, rules).astype(cd)  # (B,F,D)
+        lin = lookup_fields(params["tables"], sparse_ids, rules, prefix="lin")  # (B,F,1)
     linear_term = jnp.sum(lin[..., 0].astype(jnp.float32), axis=-1)
-    cin_feats = cin(emb, params["dense"]["cin"], rules, cd)
-    cin_term = (cin_feats @ params["dense"]["cin_out"].astype(cd))[..., 0]
+    with jax.named_scope("cin"):
+        cin_feats = cin(emb, params["dense"]["cin"], rules, cd)
+        cin_term = (cin_feats @ params["dense"]["cin_out"].astype(cd))[..., 0]
     B = emb.shape[0]
-    deep_term = mlp_apply(params["dense"]["deep"], emb.reshape(B, -1), compute_dtype=cd)[..., 0]
+    with jax.named_scope("mlp"):
+        deep_term = mlp_apply(params["dense"]["deep"], emb.reshape(B, -1),
+                              compute_dtype=cd)[..., 0]
     return (linear_term + cin_term.astype(jnp.float32)
             + deep_term.astype(jnp.float32) + params["dense"]["bias"])
 

@@ -251,7 +251,8 @@ class CheckpointSubscriber:
     def _pipe(self) -> RestorePipeline:
         return RestorePipeline(fetch_workers=self._fetch_workers,
                                decode_workers=self._decode_workers,
-                               max_inflight=self._max_inflight)
+                               max_inflight=self._max_inflight,
+                               span_prefix="cnr.refresh")
 
     @staticmethod
     def _scatter(out: np.ndarray, decoded) -> None:

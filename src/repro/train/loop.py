@@ -10,13 +10,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import trace
 from ..core.bitwidth import BitwidthController
 from ..core.checkpoint import CheckNRunManager, CheckpointConfig
 from ..core.reader_protocol import ReaderLease
@@ -89,8 +89,12 @@ class Trainer:
             self.state = template
             start_batch = 0
         else:
-            self.state = restore_train_state(template, restored,
-                                             self.bundle.tracked)
+            with trace.span("cnr.restore.place",
+                            request=restored.request,
+                            bytes=restored.nbytes()):
+                self.state = restore_train_state(template, restored,
+                                                 self.bundle.tracked)
+                jax.block_until_ready(self.state)
             start_batch = restored.extra.get("reader", {}).get("next_batch",
                                                                int(restored.step))
             if restored.degraded_from is not None:
@@ -133,7 +137,16 @@ class Trainer:
         return self.state
 
     def checkpoint(self) -> None:
-        """§3.4 workflow: stall→snapshot, resume, optimize+store in background."""
+        """§3.4 workflow: stall→snapshot, resume, optimize+store in background.
+        The stall is the ``cnr.checkpoint`` span (``stall_times``): the
+        drain of the dispatched steps, the copy, the release of the oldest
+        boundary snapshot, and the non-overlap wait for the previous
+        save."""
+        with trace.span("cnr.checkpoint") as sp:
+            self._checkpoint(sp)
+        self.stall_times.append(sp.seconds)
+
+    def _checkpoint(self, sp: trace.Span) -> None:
         extra = {}
         if self.reader is not None:
             # reader has delivered exactly `interval` batches — no in-flight gap
@@ -142,15 +155,17 @@ class Trainer:
         if self._provenance is not None:
             extra["degraded_from"] = self._provenance
             self._provenance = None
-        t0 = time.monotonic()
         snap = state_to_snapshot(self.state, self.bundle.tracked, extra)
-        self.stall_times.append(time.monotonic() - t0)
+        sp.set(request=snap.step, step=snap.step)
         # retain the two most recent boundary snapshots for exact-mode
         # partial recovery (the previous boundary matters when the save at
         # THIS boundary is the one that dies uncommitted)
         self._boundary_snaps[snap.step] = snap
-        for s in sorted(self._boundary_snaps)[:-2]:
-            del self._boundary_snaps[s]
+        # dropping the oldest snapshot frees its host arrays (a whole
+        # state's worth of pages) on this thread
+        with trace.span("cnr.snapshot.release"):
+            for s in sorted(self._boundary_snaps)[:-2]:
+                del self._boundary_snaps[s]
         # training may continue: reset the on-device touched masks and renew
         # the reader lease for the next interval
         self.state = TrainState(

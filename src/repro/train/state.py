@@ -136,7 +136,7 @@ def state_to_snapshot(state: TrainState, specs: Dict[str, TrackedSpec],
                  "step": state.step, "rng": jax.random.key_data(state.rng)}
 
     return take_snapshot(
-        step=int(jax.device_get(state.step)),
+        step=state.step,
         tables=tables, row_state=row_state, touched=touched,
         dense=dense_all, extra=extra)
 

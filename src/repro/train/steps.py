@@ -18,12 +18,35 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
 
     ``n_micro > 1`` enables gradient accumulation over micro-batches (scan) —
     activation memory scales 1/n_micro while the gradient buffer is one
-    params-sized f32 tree (sharded like the params)."""
+    params-sized f32 tree (sharded like the params).
+
+    Named scopes mark the step's phases in a profile: ``backward`` (the
+    loss and its gradient; the model's forward scopes nest inside it) and
+    ``update`` (the optimizer over every parameter, embedding rows
+    included)."""
 
     def grads_of(params, batch):
         return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
 
     def train_step(state: TrainState, batch):
+        with jax.named_scope("backward"):
+            loss, aux, grads = loss_and_grads(state, batch)
+        with jax.named_scope("update"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = apply_updates(state.params, updates)
+        touched = dict(state.touched)
+        for name, mask in aux.get("touched", {}).items():
+            if name in touched:
+                touched[name] = jnp.logical_or(touched[name], mask)
+        metrics = {k: v for k, v in aux.items() if k != "touched"}
+        metrics["loss"] = loss
+        new_state = TrainState(step=state.step + 1, params=params,
+                               opt_state=opt_state, touched=touched,
+                               rng=state.rng)
+        return new_state, metrics
+
+    def loss_and_grads(state: TrainState, batch):
         if n_micro == 1:
             (loss, aux), grads = grads_of(state.params, batch)
         else:
@@ -50,18 +73,6 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
             loss = loss_sum / n_micro
             aux = {k: jnp.mean(v, axis=0) for k, v in aux_stack.items()}
             aux["touched"] = touched_acc
-
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
-        touched = dict(state.touched)
-        for name, mask in aux.get("touched", {}).items():
-            if name in touched:
-                touched[name] = jnp.logical_or(touched[name], mask)
-        metrics = {k: v for k, v in aux.items() if k != "touched"}
-        metrics["loss"] = loss
-        new_state = TrainState(step=state.step + 1, params=params,
-                               opt_state=opt_state, touched=touched,
-                               rng=state.rng)
-        return new_state, metrics
+        return loss, aux, grads
 
     return train_step
