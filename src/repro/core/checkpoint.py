@@ -915,8 +915,10 @@ class CheckNRunManager:
         # incremental chunks record compressed global-row spans — the delta
         # index's raw material and a tighter planner bound than the writer
         # shard (full chunks are exactly range-encoded already)
-        row_spans = (compress_spans(idx)
-                     if not full and len(idx) else None)
+        row_spans = None
+        if not full and len(idx):
+            with trace.span("cnr.save.row_spans", rows=len(idx)):
+                row_spans = compress_spans(idx)
         rec = mf.ChunkRecord(
             key=key, n_rows=int(len(idx)), nbytes=len(payload),
             crc32=ObjectStore.checksum(payload), sections=sections,

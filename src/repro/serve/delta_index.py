@@ -67,16 +67,35 @@ def compress_spans(idx: np.ndarray, cap: int = MAX_CHUNK_SPANS
     run count fits; otherwise the ``cap - 1`` WIDEST gaps survive as
     separators and everything between them merges — the result is always a
     superset of ``idx`` and never wider than merging forces it to be.
-    Deterministic (ties broken by position) so sharded commits that embed
-    these spans stay byte-identical across racing committers."""
+    Deterministic (equal gaps: the later one survives, as in
+    :func:`_cap_spans`) so sharded commits that embed these spans stay
+    byte-identical across racing committers.
+
+    Array code throughout: a chunk of log-uniform ids holds ~20,000 runs,
+    and the encode workers call this once per incremental chunk."""
     n = len(idx)
     if n == 0:
         return []
+    idx = np.asarray(idx, dtype=np.int64)
     breaks = np.flatnonzero(np.diff(idx) > 1)
-    starts = np.concatenate(([0], breaks + 1))
-    ends = np.concatenate((breaks, [n - 1]))
-    spans = [[int(idx[s]), int(idx[e]) + 1] for s, e in zip(starts, ends)]
-    return _cap_spans(spans, cap)
+    starts = idx[np.concatenate(([0], breaks + 1))]
+    ends = idx[np.concatenate((breaks, [n - 1]))] + 1
+    if 0 < cap < len(starts):
+        # gap i lies between run i and run i + 1; keep the cap - 1 widest,
+        # of equal gaps the later ones: all gaps above the (cap - 1)-th
+        # widest, then the last of those equal to it
+        gaps = starts[1:] - ends[:-1]
+        keep = cap - 1
+        sep = np.zeros(0, dtype=np.int64)
+        if keep:
+            cut = np.partition(gaps, len(gaps) - keep)[len(gaps) - keep]
+            kept = gaps > cut
+            ties = np.flatnonzero(gaps == cut)
+            kept[ties[len(ties) - (keep - np.count_nonzero(kept)):]] = True
+            sep = np.flatnonzero(kept)
+        starts = starts[np.concatenate(([0], sep + 1))]
+        ends = ends[np.concatenate((sep, [len(gaps)]))]
+    return np.stack((starts, ends), axis=1).tolist()
 
 
 def _cap_spans(spans: List[List[int]], cap: int) -> List[List[int]]:

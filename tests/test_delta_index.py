@@ -87,6 +87,98 @@ def test_compress_spans_superset_property(rows, cap):
     assert spans_cover(spans, idx)
 
 
+def _compress_spans_reference(idx, cap=MAX_CHUNK_SPANS):
+    """``compress_spans`` as it was before it became array code: one Python
+    span per run, capped by :func:`_cap_spans_reference`. The oracle the
+    array version must match exactly, ties included."""
+    n = len(idx)
+    if n == 0:
+        return []
+    breaks = np.flatnonzero(np.diff(idx) > 1)
+    starts = np.concatenate(([0], breaks + 1))
+    ends = np.concatenate((breaks, [n - 1]))
+    spans = [[int(idx[s]), int(idx[e]) + 1] for s, e in zip(starts, ends)]
+    return _cap_spans_reference(spans, cap)
+
+
+def _cap_spans_reference(spans, cap):
+    if cap <= 0 or len(spans) <= cap:
+        return spans
+    gaps = sorted(((spans[i + 1][0] - spans[i][1], i)
+                   for i in range(len(spans) - 1)), reverse=True)
+    keep = sorted(i for _, i in gaps[:cap - 1])
+    out = []
+    lo = spans[0][0]
+    prev_end = spans[0][1]
+    j = 0
+    for i in range(len(spans) - 1):
+        if j < len(keep) and keep[j] == i:
+            out.append([lo, prev_end])
+            lo = spans[i + 1][0]
+            j += 1
+        prev_end = spans[i + 1][1]
+    out.append([lo, prev_end])
+    return out
+
+
+def _log_uniform_chunk(chunk, rows=2_000_384, samples=8 * 65_536):
+    """One 65,536-row chunk of an interval's sorted unique ids, drawn
+    log-uniformly over a ``rows``-row table as the DLRM benchmark cell
+    draws them (chunk 0 is the dense head, chunk 1 the sparse tail)."""
+    u = np.random.default_rng(14).random(samples) * np.log(rows)
+    ids = np.unique(np.minimum(np.exp(u).astype(np.int64) - 1, rows - 1))
+    return ids[chunk * 65_536:(chunk + 1) * 65_536]
+
+
+def _runs(idx):
+    return int(len(idx) and 1 + np.count_nonzero(np.diff(
+        np.asarray(idx, np.int64)) > 1))
+
+
+EQUIV_IDS = {
+    "empty": np.array([], np.int64),
+    "one_row": np.array([7]),
+    "one_run": np.arange(100, 140),
+    "random": np.unique(np.random.default_rng(3).integers(0, 5000, 600)),
+    # every gap equal: the cap keeps the last ones
+    "equal_gaps": np.arange(0, 600, 3),
+    # equal gaps at several positions, around and between wider ones
+    "tied_gaps": np.cumsum(np.tile([1, 6, 3, 6, 1, 9, 6, 3, 9, 1], 12)),
+    "log_uniform_head": _log_uniform_chunk(0),
+    "log_uniform_tail": _log_uniform_chunk(1),
+}
+
+
+@pytest.mark.parametrize("dtype", [np.uint32, np.int64])
+@pytest.mark.parametrize("cap", [0, 1, 2, 16, 64, "runs-1", "runs", 10**6])
+@pytest.mark.parametrize("case", sorted(EQUIV_IDS))
+def test_compress_spans_matches_reference(case, cap, dtype):
+    idx = EQUIV_IDS[case].astype(dtype)
+    if isinstance(cap, str):
+        cap = _runs(idx) - (cap == "runs-1")
+    got = compress_spans(idx, cap=cap)
+    assert got == _compress_spans_reference(idx, cap)
+    assert all(type(v) is int for s in got for v in s)
+
+
+def test_compress_spans_equal_gaps_keep_the_later():
+    # runs at 0, 3, 6, 9 with three gaps of 2: cap 3 keeps the last two
+    idx = np.array([0, 3, 6, 9])
+    assert compress_spans(idx, cap=3) == [[0, 4], [6, 7], [9, 10]]
+
+
+@given(st.lists(st.integers(min_value=0, max_value=3000), max_size=400),
+       st.integers(min_value=0, max_value=40),
+       st.sampled_from([np.uint32, np.int64]))
+@settings(max_examples=200, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow])
+def test_compress_spans_matches_reference_property(rows, cap, dtype):
+    idx = np.unique(np.asarray(rows, dtype=np.int64)).astype(dtype)
+    got = compress_spans(idx, cap=cap)
+    assert got == _compress_spans_reference(idx, cap)
+    assert all(type(v) is int for s in got for v in s)
+
+
 def test_merge_spans_union_and_cap():
     assert merge_spans([[5, 7], [0, 3], [2, 4], [7, 9]]) == [[0, 4], [5, 9]]
     assert merge_spans([[3, 3], [9, 4]]) == []  # empty/inverted drop

@@ -13,11 +13,13 @@ import numpy as np
 import pytest
 
 from repro.configs import get_cell
-from repro.core import CheckpointConfig, InMemoryStore, PAPER_DEFAULTS
+from repro.core import (CheckNRunManager, CheckpointConfig, InMemoryStore,
+                        PAPER_DEFAULTS)
 from repro.core import manifest as mf
 from repro.core import trace
 from repro.core.metrics import render_prometheus
 from repro.core.pipeline import RestorePipeline, WritePipeline
+from repro.core.snapshot import Snapshot
 from repro.train.loop import Trainer, TrainerConfig
 
 
@@ -137,6 +139,43 @@ def test_compile_counted_under_the_innermost_span_and_its_parents():
             f(jnp.arange(17, dtype=jnp.float32)).block_until_ready()
     assert inner.compiles >= 1 and cached.compiles == 0
     assert outer.compiles == inner.compiles
+
+
+@pytest.mark.parametrize("async_write", [False, True])
+def test_row_spans_span_per_incremental_chunk(async_write):
+    """Each incremental chunk opens one ``cnr.save.row_spans`` under its
+    encode span, with the chunk's rows; a full save opens none."""
+    rng = np.random.default_rng(5)
+    tab = rng.normal(size=(300, 4)).astype(np.float32)
+    store = InMemoryStore()
+    mgr = CheckNRunManager(store, CheckpointConfig(
+        policy="consecutive", quant=PAPER_DEFAULTS[4],
+        async_write=async_write, chunk_rows=64))
+    try:
+        with trace.record():
+            for step in (1, 2):
+                tab[rng.random(300) < 0.5] += 1.0
+                mgr.save(Snapshot(
+                    step=step, tables={"emb": tab.copy()},
+                    row_state={"emb": {}},
+                    touched={"emb": rng.random(300) < 0.5},
+                    dense={"w": np.zeros(8, np.float32)}, extra={}),
+                    block=True)
+    finally:
+        mgr.close()
+    spans = trace.drain()
+    full, incr = mf.load(store, 1), mf.load(store, 2)
+    assert (full.kind, incr.kind) == ("full", "incremental")
+    assert not [s for s in spans if s.request == 1
+                and s.name == "cnr.save.row_spans"]
+    of = [s for s in spans if s.request == 2]
+    rs = by_name(of, "cnr.save.row_spans")
+    chunks = [c for c in incr.tables["emb"].chunks if c.n_rows]
+    assert len(rs) == len(chunks) >= 2
+    assert sorted(s.attrs["rows"] for s in rs) == sorted(
+        c.n_rows for c in chunks)
+    encode = {s.id for s in by_name(of, "cnr.save.encode")}
+    assert all(s.parent_id in encode for s in rs)
 
 
 # ------------------------------------------------------- a tiny Trainer
