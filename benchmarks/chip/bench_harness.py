@@ -18,6 +18,16 @@ placement of the restored state.
 A traffic mix's ``mode`` is ``train`` (train and save through the
 window) or ``resume`` (write a chain in set-up, then resume from it over
 and over in the window).
+
+A configuration's ``program`` block names the program's builder:
+``arch``, ``config_class`` and ``shape``, and optionally the device mesh
+of its deployment, ``"mesh": {"shape": [2, 2], "axes": ["data",
+"model"]}``, with the axis names of the program's sharding rules
+(``dist/sharding.recsys_rules``: ``batch`` over ``data``, table rows over
+``("data", "model")``). A cell of such a configuration asks for as many
+chips as the mesh holds; the harness builds the mesh over the first of
+them, passes it to the builder and draws the seed's state already
+sharded. Without the key the configuration runs on one device.
 """
 
 from __future__ import annotations
@@ -28,6 +38,7 @@ import gc
 import importlib
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
@@ -114,10 +125,12 @@ class CompileLog:
 
 
 # ------------------------------------ the program (imported when first used)
-def build_bundle(cfg: dict, seed: int):
+def build_bundle(cfg: dict, seed: int, mesh=None):
     """The configuration's cell through the program's builder, with its
-    state drawn from the seed in one jitted call on the device."""
+    state drawn from the seed in one jitted call on the device; over
+    ``mesh``, drawn already sharded by the program's rules."""
     import jax
+    from jax.sharding import NamedSharding, PartitionSpec
     from repro.configs._families import recsys_cell
 
     prog = cfg["program"]
@@ -126,8 +139,13 @@ def build_bundle(cfg: dict, seed: int):
     fields = {f.name for f in dataclasses.fields(cls)}
     kw = {k: tuple(v) if isinstance(v, list) else v
           for k, v in cfg.items() if k in fields}
-    base = recsys_cell(prog["arch"], cls(**kw), prog["shape"])
-    make = jax.jit(base.make_state)
+    base = recsys_cell(prog["arch"], cls(**kw), prog["shape"], mesh=mesh)
+    if mesh is None:
+        make = jax.jit(base.make_state)
+    else:
+        make = jax.jit(base.make_state, out_shardings=jax.tree.map(
+            lambda p: NamedSharding(mesh, p), base.state_pspecs(),
+            is_leaf=lambda x: isinstance(x, PartitionSpec)))
     key = jax.random.key(seed)
     bundle = dataclasses.replace(base)
     # the program's Trainer draws its state with bundle.make_state()
@@ -304,6 +322,13 @@ class Sampler:
                     dense=sorted(tuple(int(x) for x in d) for d in dense))
 
 
+def memory_peaks(devices) -> List[int]:
+    """``peak_bytes_in_use`` of each device (0 where the backend keeps no
+    statistics)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
 def warm_buckets(traffic: dict, dims: List[int]) -> None:
     """Compile (or load from the cache) the quantize+pack and chunk-hash
     programs of every power-of-two row bucket a chunk of this mix can land
@@ -353,13 +378,13 @@ class Run:
 
     def __init__(self, cell: dict, cfg: dict, traffic: dict, seed: int,
                  seconds: float, trace: bool, peaks: dict,
-                 compiles: CompileLog):
+                 compiles: CompileLog, devices: list, mesh=None):
         from bench_gen import BatchGen
         from bench_reftrain import load_model
 
         self.cell, self.cfg, self.traffic = cell, cfg, traffic
         self.seed, self.seconds, self.trace = seed, seconds, trace
-        self.compiles = compiles
+        self.compiles, self.devices = compiles, devices
         self.rec = RunRecord(cell=cell, cfg=cfg, traffic=traffic, peaks=peaks)
         self.model = load_model(cell["config"])
         self.gen = BatchGen(cfg, traffic["ids"], seed,
@@ -371,7 +396,7 @@ class Run:
             shutil.rmtree(d, ignore_errors=True)
         self.store = timed_store(self.store_root, self.spans)
         self.ckpt = checkpoint_config(traffic)
-        self.bundle, self.base = build_bundle(cfg, seed)
+        self.bundle, self.base = build_bundle(cfg, seed, mesh)
         self.Trainer = make_trainer_class(self.spans)
         self.sampler = Sampler(self.bundle, seed,
                                traffic["check"]["rows_per_table"],
@@ -409,12 +434,6 @@ class Run:
             self.rec.compiles_in_window = self.compiles.since_mark(
                 BACKEND_COMPILE)
             self.rec.loads_in_window = self.compiles.since_mark(CACHE_LOAD)
-
-    def memory_peak(self) -> int:
-        import jax
-
-        stats = jax.devices()[0].memory_stats() or {}
-        return int(stats.get("peak_bytes_in_use", 0))
 
     def add_check(self, name: str, value: float) -> None:
         """Compare ``value`` with its limit from the configuration's or the
@@ -546,7 +565,7 @@ class Run:
                 "durable {:.3f} s".format(len(self.rec.saves), *(
                     float(np.mean([s_[k] for s_ in self.rec.saves]))
                     for k in ("stall_s", "wall_time_s", "durable_s"))))
-        peak = self.memory_peak()
+        peaks = memory_peaks(self.devices)
         tr.state = None
         tr.close()
         del tr, taken
@@ -555,7 +574,7 @@ class Run:
         self.check_saves(view, steps, captures)
         self.check_first_steps(prog_first)
         log(f"the check took {time.monotonic() - t:.1f} s after the window")
-        return dict(peak=peak)
+        return dict(mem_peaks=peaks)
 
     def check_saves(self, view, steps: List[int], captures: dict) -> None:
         """Compare the saves of the window with what they should hold: a
@@ -683,12 +702,12 @@ class Run:
         for d, h, p_ in zip(done, host, place):
             self.rec.restores.append(dict(resume_s=d["resume_s"],
                                           host_s=h, place_s=p_))
-        peak = self.memory_peak()
+        peaks = memory_peaks(self.devices)
         gc.collect()
         t = time.monotonic()
         self.check_restores(idx, live, done, last)
         log(f"the check took {time.monotonic() - t:.1f} s after the window")
-        return dict(peak=peak)
+        return dict(mem_peaks=peaks)
 
     def check_restores(self, idx, live, done, step) -> None:
         """Each restore of the window against the chain replayed by the
@@ -730,7 +749,9 @@ class Run:
             shutil.rmtree(self.store_root, ignore_errors=True)
         if self.trace:
             from bench_trace import read_xplane, summarize
-            self.rec.trace = summarize(read_xplane(self.trace_dir))
+            t = self.rec.trace = summarize(read_xplane(self.trace_dir))
+            log(f"trace: {t.n_chips} chip(s) with ops, busy {t.busy_s:.3f} s "
+                f"of {t.window_s:.3f} s")
             shutil.rmtree(self.trace_dir, ignore_errors=True)
         return out
 
@@ -770,11 +791,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     traffic = load_json(HERE, "traffic", f"{cell['traffic']}.json")
     cfg.update((overrides or {}).get("config", {}))
     traffic.update((overrides or {}).get("traffic", {}))
+    mesh_spec = cfg["program"].get("mesh")
+    if mesh_spec and math.prod(mesh_spec["shape"]) != cell["chips"]:
+        raise SystemExit(f"configuration {cell['config']!r} names a mesh of "
+                         f"{mesh_spec['shape']} devices; workload "
+                         f"{workload!r} asks for {cell['chips']} chip(s)")
     devices = jax.devices()
     dev = devices[0]
     if require_tpu and (dev.platform != "tpu" or len(devices) < cell["chips"]):
         raise SystemExit(f"needs {cell['chips']} TPU chip(s); JAX sees "
                          f"{len(devices)} {dev.platform} device(s)")
+    devices = devices[:cell["chips"]]
     peaks = peaks_for(dev.device_kind) if require_tpu else \
         peaks_for("TPU v5 lite")
     from repro.launch.compile_cache import enable_compile_cache
@@ -785,7 +812,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     compiles = CompileLog()
     jax.monitoring.register_event_duration_secs_listener(compiles)
 
-    run = Run(cell, cfg, traffic, seed, seconds, trace, peaks, compiles)
+    mesh = None
+    if mesh_spec:
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(mesh_spec["shape"], mesh_spec["axes"], devices)
+    run = Run(cell, cfg, traffic, seed, seconds, trace, peaks, compiles,
+              devices, mesh)
     out = run.execute()
     rec = run.rec
     e2e, per = cell_metrics(bench, workload)
@@ -804,7 +836,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         f"{rec.compiles_in_window}; compile-cache loads in the window: "
         f"{len(rec.loads_in_window)} {rec.loads_in_window}")
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": cell["chips"], "memory_peak_bytes": out["peak"]}
+              "count": cell["chips"],
+              "memory_peak_bytes": max(out["mem_peaks"]),
+              "memory_peak_bytes_by_chip": out["mem_peaks"]}
     result = {"correct": all(c["value"] <= c["limit"]
                              for c in run.checks.values()),
               "attempted": rec.attempted, "failed": rec.failed,

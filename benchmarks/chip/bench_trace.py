@@ -5,12 +5,16 @@ the rest works on those lists alone, so it is checked on small made-up
 traces. Times are in seconds on the trace's own clock, on which the
 device's events and the host's spans both lie.
 
-* device ops: events of each device plane's ``XLA Ops`` line — busy time
-  is the union of their intervals, the idle share 1 minus busy over the
-  window;
+* chips: a chip is a device plane with events on its ``XLA Ops`` line;
+  planes without ops (``/device:CUSTOM:Megascale Trace``) are left out;
+* device ops: a chip's busy time is the union of its ops' intervals;
+  ``busy_s`` is the mean over the chips, so the idle share is the mean
+  per-chip idle share; idle gaps are the stretches in which every chip is
+  idle;
 * modules: events of the ``XLA Modules`` line, one per execution of a
   compiled program, named after the jitted function (``train_step``,
-  ``quant_pack_pallas``, ``chunk_hash_pallas`` ...);
+  ``quant_pack_pallas``, ``chunk_hash_pallas`` ...), summed over the chips
+  in chip-seconds;
 * host spans: the benchmark's own ``TraceAnnotation`` spans, all named
   ``bench.<what>``, from the host threads.
 """
@@ -164,9 +168,10 @@ def top(d: Dict[str, float], n: int = 10) -> List[list]:
 @dataclasses.dataclass
 class Summary:
     window_s: float
-    busy_s: float                         # averaged over the devices
-    module_s: Dict[str, float]            # summed over the devices
+    busy_s: float                         # mean over the chips
+    module_s: Dict[str, float]            # summed over the chips
     gaps_by_span: Dict[str, float]
+    n_chips: int = 1                      # device planes that hold ops
 
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
@@ -178,8 +183,9 @@ def summarize(tr: Trace, window: str = "bench.window") -> Summary:
     if not wins:
         raise ValueError(f"no {window!r} span in the trace")
     lo, hi = wins[0]
-    n_dev = max(len(tr.ops), 1)
-    busy = sum(busy_seconds(o, lo, hi) for o in tr.ops) / n_dev
+    chips = [i for i, o in enumerate(tr.ops) if o]
+    busy = sum(busy_seconds(tr.ops[i], lo, hi) for i in chips) / max(
+        len(chips), 1)
     mods: Dict[str, float] = {}
     for dev in tr.modules:
         for k, v in seconds_by_name(dev, lo, hi, module_key).items():
@@ -187,4 +193,5 @@ def summarize(tr: Trace, window: str = "bench.window") -> Summary:
     gaps = idle_gaps([iv for o in tr.ops for iv in o], lo, hi)
     inner = [sp for sp in tr.spans if sp[2] != window]
     return Summary(window_s=hi - lo, busy_s=busy, module_s=mods,
-                   gaps_by_span=attribute_gaps(gaps, inner))
+                   gaps_by_span=attribute_gaps(gaps, inner),
+                   n_chips=len(chips))

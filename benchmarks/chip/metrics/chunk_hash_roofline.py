@@ -1,6 +1,8 @@
 """Roofline share of the on-device chunk hash: the payload words it must
 read for the window's chunks, over the summed device time of its
-executions and the HBM peak (%). Bytes bound it."""
+executions and one chip's HBM peak (%). On N chips the time is in
+chip-seconds, summed over the chips that run the kernel, so one chip's
+peak is right. Bytes bound it."""
 
 from bench_yardstick import chunk_hash_bytes
 

@@ -1,6 +1,7 @@
-"""Idle share of the device over the traced window of a resume cell: 1
-minus the union of the device's operation intervals over the window
-(%)."""
+"""Idle share of the chips over the traced window of a resume cell: 1
+minus each chip's busy time (the union of its operation intervals) over
+the window, averaged over the chips, which are the device planes that
+hold ops (%)."""
 
 
 def read(rec):

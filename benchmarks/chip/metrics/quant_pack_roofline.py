@@ -1,8 +1,10 @@
 """Roofline share of the fused quantize+pack kernel: the bytes it must
 move for the window's chunks (f32 rows in, packed words and per-row scale
 and zero out, over each chunk's real rows, not its power-of-two bucket),
-over the summed device time of its executions and the HBM peak (%).
-Bytes bound it against the chip's published peaks: the 4-bit adaptive
+over the summed device time of its executions and one chip's HBM peak
+(%). On N chips the time is in chip-seconds, summed over the chips that
+run the kernel, so one chip's peak is right. Bytes bound it against the
+chip's published peaks: the 4-bit adaptive
 search does about 40 vector operations a byte read (9 steps, two
 candidate ranges of about 8 operations an element each), under the
 197e12 / 819e9 = 240 operations a byte at which compute would bind."""

@@ -75,6 +75,66 @@ def test_trace_window_clips_and_requires_window():
         T.summarize(T.Trace(ops=[[]], modules=[[]], spans=[]))
 
 
+def test_trace_leaves_out_planes_without_ops():
+    tr = synthetic_trace()
+    tr.ops.append([])             # a device plane with no ops (Megascale)
+    tr.modules.append([])
+    s = T.summarize(tr)
+    assert s.n_chips == 1
+    assert s.busy_s == pytest.approx(4.0)
+    assert s.idle_share() == pytest.approx(0.6)
+
+
+def test_trace_four_chips_mean_busy_and_summed_modules():
+    # chips busy 2, 4, 6 and 2 s of [0, 10]; every chip idle over 6-7, 9-10
+    busy = [(1.0, 3.0), (1.0, 5.0), (0.0, 6.0), (7.0, 9.0)]
+    tr = T.Trace(ops=[[(s, e, "fusion")] for s, e in busy] + [[]],
+                 modules=[[(s, e, "jit_train_step(3)")] for s, e in busy]
+                 + [[]],
+                 spans=[(0.0, 10.0, "bench.window"),
+                        (6.0, 7.0, "bench.checkpoint")])
+    s = T.summarize(tr)
+    assert s.n_chips == 4
+    assert s.busy_s == pytest.approx(3.5)
+    assert s.idle_share() == pytest.approx(0.65)
+    assert s.module_s == pytest.approx({"train_step": 14.0})
+    assert s.gaps_by_span == pytest.approx(
+        {"bench.checkpoint": 1.0, "host.other": 1.0})
+
+
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_train_step_ms_and_save_mfu_read_per_chip(n_chips):
+    import bench_harness as H
+
+    # every chip runs 100 steps of 20 ms; two saves of 0.5 s each
+    rec = H.RunRecord(cell={"chips": n_chips}, cfg={},
+                      traffic={"mode": "train"},
+                      peaks=Y.peaks_for("TPU v5 lite"), steps=100,
+                      saves=[dict(rows_by_dim={64: 1000}, nbytes=40_000,
+                                  durable_s=0.5)] * 2)
+    rec.trace = T.Summary(window_s=10.0, busy_s=2.0,
+                          module_s={"train_step": 2.0 * n_chips},
+                          gaps_by_span={}, n_chips=n_chips)
+    assert H.load_reader("train_step_ms")(rec) == pytest.approx(20.0)
+    least = 2 * (1000 * 64 * 4 + 40_000) / (819e9 * n_chips)
+    assert H.load_reader("save_mfu")(rec) == pytest.approx(100.0 * least)
+
+
+def test_memory_peaks_read_every_device():
+    import bench_harness as H
+
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    assert H.memory_peaks([Dev({"peak_bytes_in_use": 5}),
+                           Dev({"peak_bytes_in_use": 9}), Dev(None),
+                           Dev({})]) == [5, 9, 0, 0]
+
+
 # ------------------------------------------------- byte and FLOP counts
 @pytest.mark.parametrize("rows,dim,bits,want", [
     # f32 rows in + packed words out + f32 scale and zero per row
